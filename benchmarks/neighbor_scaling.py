@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis
 from repro.configs import get_config
 from repro.core import kb_create, make_carls_train_step, \
     make_inline_baseline_step
@@ -59,8 +58,8 @@ def run(quick: bool = False) -> List[Dict]:
 
         step_c = jax.jit(make_carls_train_step(model, opt, DIST))
         t_carls = _time_steps(step_c, (params, opt.init(params), kb, jb))
-        f_carls = cost_analysis(step_c.lower(params, opt.init(params),
-                                             kb, jb).compile())["flops"]
+        f_carls = step_c.lower(params, opt.init(params),
+                               kb, jb).compile().cost_analysis()["flops"]
 
         jb2 = dict(jb)
         jb2["neighbor_tokens"] = jnp.asarray(
@@ -68,8 +67,8 @@ def run(quick: bool = False) -> List[Dict]:
         step_b = jax.jit(make_inline_baseline_step(model, opt, DIST,
                                                    num_neighbors=K))
         t_base = _time_steps(step_b, (params, opt.init(params), jb2))
-        f_base = cost_analysis(step_b.lower(params, opt.init(params),
-                                            jb2).compile())["flops"]
+        f_base = step_b.lower(params, opt.init(params),
+                              jb2).compile().cost_analysis()["flops"]
         rows.append({"name": f"neighbor_scaling/K={K}/carls",
                      "us_per_call": t_carls * 1e6,
                      "derived": f"flops={f_carls:.3g}"})

@@ -304,8 +304,8 @@ class ShardedIVFIndex:
 def build_sharded_ivf_index(table, n_shards: int, *, nlist: int = 64,
                             iters: int = 8, tol: float = 1e-4,
                             base: Optional[ShardedIVFIndex] = None,
-                            shards: Optional[Sequence[int]] = None
-                            ) -> ShardedIVFIndex:
+                            shards: Optional[Sequence[int]] = None,
+                            put=jnp.asarray) -> ShardedIVFIndex:
     """Cluster a row-sharded bank snapshot into per-shard sub-indexes.
 
     With ``base`` and ``shards`` given, only those shards are re-clustered;
@@ -317,7 +317,9 @@ def build_sharded_ivf_index(table, n_shards: int, *, nlist: int = 64,
     caller as ``result.bucket_cap != base.bucket_cap``.
 
     Deterministic like ``build_ivf_index``: same snapshot, same shard set,
-    same index."""
+    same index. ``put`` turns each packed host array into the index's
+    device array; the sharded engine passes a placement over its mesh
+    rows, so the index never passes whole through one device."""
     tbl = np.asarray(table, np.float32)
     N, D = tbl.shape
     if N % n_shards:
@@ -355,6 +357,11 @@ def build_sharded_ivf_index(table, n_shards: int, *, nlist: int = 64,
                 built[s] = (np.asarray(centroids), np.asarray(assign))
         cap = _round_capacity(max(int(np.bincount(a, minlength=C).max())
                                   for _, a in built.values()))
+    # host copies of the sub-indexes kept from base (its arrays may be
+    # sharded over a mesh)
+    kept = ({} if base is None else
+            {name: np.asarray(getattr(base, name)) for name in
+             ("centroids", "packed_vecs", "packed_ids", "bucket_occ")})
     all_cent = np.zeros((n_shards * C, D), np.float32)
     all_vecs = np.zeros((n_shards * C * cap, D), np.float32)
     all_ids = np.full((n_shards * C * cap,), -1, np.int32)
@@ -371,16 +378,14 @@ def build_sharded_ivf_index(table, n_shards: int, *, nlist: int = 64,
             all_ids[lo:hi] = pi
             all_occ[s * C:(s + 1) * C] = np.bincount(assign, minlength=C)
         else:                           # keep base's sub-index verbatim
-            all_cent[s * C:(s + 1) * C] = np.asarray(
-                base.centroids[s * C:(s + 1) * C])
-            all_vecs[lo:hi] = np.asarray(base.packed_vecs[lo:hi])
-            all_ids[lo:hi] = np.asarray(base.packed_ids[lo:hi])
-            all_occ[s * C:(s + 1) * C] = np.asarray(
-                base.bucket_occ[s * C:(s + 1) * C])
-    return ShardedIVFIndex(jnp.asarray(all_cent), jnp.asarray(all_vecs),
-                           jnp.asarray(all_ids), n_shards=n_shards, nlist=C,
+            all_cent[s * C:(s + 1) * C] = kept["centroids"][s * C:(s + 1) * C]
+            all_vecs[lo:hi] = kept["packed_vecs"][lo:hi]
+            all_ids[lo:hi] = kept["packed_ids"][lo:hi]
+            all_occ[s * C:(s + 1) * C] = kept["bucket_occ"][s * C:(s + 1) * C]
+    return ShardedIVFIndex(put(all_cent), put(all_vecs),
+                           put(all_ids), n_shards=n_shards, nlist=C,
                            bucket_cap=cap, n_rows=N,
-                           bucket_occ=jnp.asarray(all_occ))
+                           bucket_occ=put(all_occ))
 
 
 # ---------------------------------------------------------------------------
@@ -444,12 +449,12 @@ class QuantizedShardedIVFIndex:
                  "packed_offset", "packed_ids", "n_shards", "nlist",
                  "bucket_cap", "n_rows", "bucket_occ", "base")
 
-    def __init__(self, base: ShardedIVFIndex):
+    def __init__(self, base: ShardedIVFIndex, put=jnp.asarray):
         codes, scale, offset = _quantize_packed(base.packed_vecs)
         self.centroids = base.centroids
-        self.packed_codes = jnp.asarray(codes)
-        self.packed_scale = jnp.asarray(scale)
-        self.packed_offset = jnp.asarray(offset)
+        self.packed_codes = put(codes)
+        self.packed_scale = put(scale)
+        self.packed_offset = put(offset)
         self.packed_ids = base.packed_ids
         self.n_shards = base.n_shards
         self.nlist = base.nlist

@@ -178,6 +178,31 @@ class ShardedBackend:
             self.dist, packed_scale=packed_scale,
             packed_offset=packed_offset)
 
+    def create(self, num_entries: int, dim: int, *, dtype,
+               key) -> KBState:
+        """A ``KBState`` born sharded over the mesh: every device
+        materializes only the rows it owns, so a bank sized for the whole
+        mesh never passes through one device."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        mesh = self.dist.mesh
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(mesh, spec),
+            self._skb.kb_pspecs(self.dist),
+            is_leaf=lambda x: isinstance(x, PartitionSpec))
+        return jax.jit(lambda: kbm.kb_create(num_entries, dim, dtype=dtype,
+                                             key=key),
+                       out_shardings=shardings)()
+
+    def put_rows(self, arr) -> jax.Array:
+        """A host array sharded over the mesh rows by its leading axis —
+        the layout ``sharded_kb_nn_search_ivf`` reads its sub-indexes in,
+        so neither a build nor a search moves index bytes through one
+        device."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        spec = PartitionSpec(self._skb.kb_axes(self.dist),
+                             *([None] * (np.ndim(arr) - 1)))
+        return jax.device_put(arr, NamedSharding(self.dist.mesh, spec))
+
     @property
     def n_shards(self) -> int:
         """Total bank shards = product of the mesh axes the rows span."""
@@ -243,12 +268,14 @@ class PallasBackend:
             step=state.step + 1)
 
     def nn_search(self, state, queries, k, *, exclude_ids=None):
-        if exclude_ids is not None:
-            return kbm.kb_nn_search(state, queries, k,
-                                    exclude_ids=exclude_ids)
-        from repro.kernels.nn_search import nn_search_pallas
-        return nn_search_pallas(queries, state.table, k,
-                                interpret=self.interpret)
+        from repro.kernels.nn_search import (nn_search_pallas,
+                                             overfetch_exclude_topk)
+        search = (lambda kk: nn_search_pallas(queries, state.table, kk,
+                                              interpret=self.interpret))
+        if exclude_ids is None:
+            return search(k)
+        return overfetch_exclude_topk(search, state.table.shape[0], k,
+                                      exclude_ids)
 
 
 def make_backend(name: str, *, dist: Optional[DistContext] = None,
@@ -434,6 +461,9 @@ class KBEngine:
                 self.state = kbm.kb_create(rows, dim, dtype=jnp.int8)
                 self._qscale = jnp.ones((rows,), jnp.float32)
                 self._qoffset = jnp.zeros((rows,), jnp.float32)
+        elif sharded:
+            self.state = self.backend.create(rows, dim, dtype=dtype, key=key)
+            self._qscale = self._qoffset = None
         else:
             self.state = kbm.kb_create(rows, dim, dtype=dtype, key=key)
             self._qscale = self._qoffset = None
@@ -1003,8 +1033,10 @@ class KBEngine:
                 self.state.table, self._qscale, self._qoffset), np.float32)
         else:
             table = np.asarray(self.state.table, np.float32)
+        put = (self.backend.put_rows if self.ann_shards > 1
+               else jnp.asarray)
         wrap = ((lambda ix: ix) if self.storage != "int8" else
-                (lambda ix: (QuantizedShardedIVFIndex(ix)
+                (lambda ix: (QuantizedShardedIVFIndex(ix, put=put)
                              if isinstance(ix, ShardedIVFIndex)
                              else QuantizedIVFIndex(ix))))
         if self.ann_shards == 1:
@@ -1017,9 +1049,10 @@ class KBEngine:
                 else prev if isinstance(prev, ShardedIVFIndex) else None)
         index = build_sharded_ivf_index(table, self.ann_shards,
                                         nlist=self.ann_nlist, iters=iters,
-                                        base=base, shards=shards)
+                                        base=base, shards=shards, put=put)
         if index is base:                       # empty shard list: no-op
             return 0
+        index = wrap(index)
         if (base is not None and shards is not None
                 and index.bucket_cap == base.bucket_cap):
             # partial rebuild: untouched shards keep their old clocks
@@ -1028,9 +1061,9 @@ class KBEngine:
             for s in rebuilt:
                 new_built[s] = built_at[s]
             built_at = new_built
-            self.set_ann_index(wrap(index), built_at_shard_writes=built_at)
+            self.set_ann_index(index, built_at_shard_writes=built_at)
             return len(rebuilt)
-        self.set_ann_index(wrap(index), built_at_shard_writes=built_at)
+        self.set_ann_index(index, built_at_shard_writes=built_at)
         return self.ann_shards                  # full (re)build
 
     def warmup(self, max_batch: int = 256) -> None:

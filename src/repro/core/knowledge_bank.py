@@ -51,6 +51,9 @@ class KBState(NamedTuple):
 
 
 _EMA_DECAY = 0.9
+# the bank stores fp32 rows, so its score matmuls run at full fp32 precision
+# on every platform (the TPU default rounds f32 operands through bf16)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 class FeatureStore(NamedTuple):
@@ -236,7 +239,8 @@ def kb_nn_search(kb: KBState, queries: jnp.ndarray, k: int,
 
     queries: (B, D) -> (scores (B, k), ids (B, k)). Reference path; the
     blocked Pallas kernel lives in repro.kernels.nn_search."""
-    scores = queries.astype(jnp.float32) @ kb.table.T.astype(jnp.float32)
+    scores = jnp.matmul(queries.astype(jnp.float32),
+                        kb.table.T.astype(jnp.float32), precision=HIGHEST)
     if exclude_ids is not None:
         B = queries.shape[0]
         excl = jnp.zeros(scores.shape, bool).at[
@@ -299,7 +303,8 @@ def quantized_scores(queries: jnp.ndarray, codes: jnp.ndarray,
     ``s * (q . c) + o * sum(q)``. queries: (B, D); codes: (N, D) ->
     (B, N) f32, exact w.r.t. the quantized values."""
     qf = queries.astype(jnp.float32)
-    raw = qf @ codes.T.astype(jnp.float32)                   # (B, N)
+    raw = jnp.matmul(qf, codes.T.astype(jnp.float32),
+                     precision=HIGHEST)                      # (B, N)
     return raw * scale[None, :] + jnp.sum(qf, -1, keepdims=True) * offset
 
 

@@ -39,8 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-from repro.core.knowledge_bank import (KBState, ema_step,
+from repro.core.knowledge_bank import (HIGHEST, KBState, ema_step,
                                        lazy_grad_contribution, pending_delta)
 from repro.sharding.partition import DistContext
 
@@ -79,7 +78,7 @@ class OwnerShard:
                  flat_ids: Optional[jnp.ndarray] = None):
         idx = 0
         for a in axes:
-            idx = idx * axis_size(a) + jax.lax.axis_index(a)
+            idx = idx * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         self.n_local = n_local
         self.offset = idx * n_local
         if flat_ids is not None:
@@ -137,7 +136,7 @@ def sharded_kb_lookup(kb: KBState, ids: jnp.ndarray, dist: DistContext, *,
         vals = jax.lax.psum(own.mask(rows), axes)
         return vals, table, version, gsum, gcnt, gsq
 
-    vals, table, version, gsum, gcnt, gsq = shard_map(
+    vals, table, version, gsum, gcnt, gsq = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(specs.table, specs.version, specs.grad_sum, specs.grad_cnt,
                   specs.grad_sqnorm, P(*([None] * ids.ndim))),
@@ -169,7 +168,7 @@ def sharded_kb_update(kb: KBState, ids, values, dist: DistContext) -> KBState:
                 own.set(gcnt, zero),
                 own.set(gsq, zero))
 
-    table, version, gsum, gcnt, gsq = shard_map(
+    table, version, gsum, gcnt, gsq = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(specs.table, specs.version, specs.grad_sum, specs.grad_cnt,
                   specs.grad_sqnorm, P(*([None] * ids.ndim)),
@@ -210,7 +209,7 @@ def sharded_kb_lazy_grad(kb: KBState, ids, grads, dist: DistContext,
     if mask is not None:
         in_specs = in_specs + (P(*([None] * mask.ndim)),)
         args = args + (mask,)
-    gsum, gcnt, gsq, ema = shard_map(
+    gsum, gcnt, gsq, ema = jax.shard_map(
         body, mesh=dist.mesh, in_specs=in_specs,
         out_specs=(specs.grad_sum, specs.grad_cnt, specs.grad_sqnorm,
                    specs.norm_ema),
@@ -233,7 +232,7 @@ def sharded_kb_flush(kb: KBState, dist: DistContext, *, lazy_lr: float = 0.1,
         return (table, version, jnp.zeros_like(gsum), jnp.zeros_like(gcnt),
                 jnp.zeros_like(gsq))
 
-    table, version, gsum, gcnt, gsq = shard_map(
+    table, version, gsum, gcnt, gsq = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(specs.table, specs.version, specs.grad_sum, specs.grad_cnt,
                   specs.grad_sqnorm),
@@ -263,7 +262,9 @@ def sharded_kb_nn_search(kb: KBState, queries, k: int, dist: DistContext,
             from repro.kernels.ops import nn_search_topk
             ls, li = nn_search_topk(queries, table, kk)
         else:
-            scores = queries.astype(jnp.float32) @ table.T.astype(jnp.float32)
+            scores = jnp.matmul(queries.astype(jnp.float32),
+                                table.T.astype(jnp.float32),
+                                precision=HIGHEST)
             ls, li = jax.lax.top_k(scores, kk)
         li = li + own.offset
         # gather candidates from every shard: (B, k*n_shards)
@@ -274,7 +275,7 @@ def sharded_kb_nn_search(kb: KBState, queries, k: int, dist: DistContext,
         ids = jnp.take_along_axis(li, gi, axis=1)
         return gs, ids
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(specs.table, P(None, None)),
         out_specs=(P(None, None), P(None, None)),
@@ -334,12 +335,14 @@ def sharded_kb_nn_search_ivf(table, centroids, packed_vecs, packed_ids,
         cap = pvec.shape[0] // C
         qf = q.astype(jnp.float32)
         # stage 1: probe this shard's own coarse quantizer
-        cscore = qf @ cent.T.astype(jnp.float32)             # (B, C)
+        cscore = jnp.matmul(qf, cent.T.astype(jnp.float32),
+                            precision=HIGHEST)               # (B, C)
         _, probes = jax.lax.top_k(cscore, nprobe)
         # stage 2: score only the probed buckets (local shortlist)
         cv = pvec.reshape(C, cap, D)[probes].reshape(B, nprobe * cap, D)
         ci = pid.reshape(C, cap)[probes].reshape(B, nprobe * cap)
-        s = jnp.einsum("bd,bld->bl", qf, cv.astype(jnp.float32))
+        s = jnp.einsum("bd,bld->bl", qf, cv.astype(jnp.float32),
+                       precision=HIGHEST)
         if qargs:       # int8 codes: exact dequantized-score decomposition
             pscl, poff = qargs
             cs = pscl.reshape(C, cap)[probes].reshape(B, nprobe * cap)
@@ -373,7 +376,8 @@ def sharded_kb_nn_search_ivf(table, centroids, packed_vecs, packed_ids,
                          jnp.where(valid, ids, 0).reshape(-1))
         rows = jax.lax.psum(
             own.mask(own.gather(table).astype(jnp.float32)), axes)
-        s_live = jnp.einsum("bd,bkd->bk", qf, rows.reshape(B, kq, D))
+        s_live = jnp.einsum("bd,bkd->bk", qf, rows.reshape(B, kq, D),
+                            precision=HIGHEST)
         s_live = jnp.where(valid, s_live, -jnp.inf)
         order = jnp.argsort(-s_live, axis=-1)[:, :k]
         return (jnp.take_along_axis(s_live, order, axis=1),
@@ -386,7 +390,7 @@ def sharded_kb_nn_search_ivf(table, centroids, packed_vecs, packed_ids,
     if quantized:
         in_specs = in_specs + (P(axes), P(axes))
         args = args + (packed_scale, packed_offset)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=in_specs,
         out_specs=(P(None, None), P(None, None)),

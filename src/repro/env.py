@@ -106,6 +106,22 @@ def has_accelerator() -> bool:
     return default_backend() in ("gpu", "tpu")
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and nothing here overrides it; otherwise the cache
+    lives at ``<checkout>/.jax_cache``. The path is part of every cache
+    key, so it is fixed: never derived from a temp name, a pid or the
+    clock. Launchers call this from ``main()``, never at import."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    path = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 # ---------------------------------------------------------------------------
 # KernelConfig: the single source of kernel execution settings
 # ---------------------------------------------------------------------------
@@ -238,9 +254,10 @@ def fit_block_rows(dim: int, *, want: Optional[int] = None,
 
 def fused_lookup_block(batch: int, dim: int, *, want: Optional[int] = None,
                        budget: Optional[int] = None) -> int:
-    """Bank-tile rows for the fused-lookup family: those kernels hold a
-    (B, n_block) one-hot, a (B, D) fp32 accumulator, and ~10 streamed
-    (n_block, D) tiles in VMEM at once. For B > 4k ids the old fixed
+    """Bank-tile rows for the fused-lookup family: those kernels hold an
+    (n_block, B) one-hot, a resident (B, D) fp32 output, and ~10 streamed
+    (n_block, D) tiles in VMEM at once; every row is padded to whole
+    128-lane vregs. For B > 4k ids the old fixed
     n_block=512 overflows a 16 MiB core — this shrinks the tile until the
     working set fits (and the batch-shaped scratch alone exceeding the
     budget raises rather than producing an illegal tile)."""
@@ -248,10 +265,13 @@ def fused_lookup_block(batch: int, dim: int, *, want: Optional[int] = None,
     want = cfg.block_ids if want is None else want
     budget = cfg.vmem_limit_bytes if budget is None else budget
     b = max(8, -(-batch // 8) * 8)                  # padded batch
-    fixed = 2 * b * max(1, dim) * 4                 # acc scratch + vals out
-    # per bank row: one one-hot column (B floats, double-buffered compute)
+    d = -(-max(1, dim) // 128) * 128                # lane-padded row width
+    # the resident (B, D) output (2 buffers), the (1, B) id row (8
+    # sublanes, 2 buffers), and one (512, D) gather product in flight
+    fixed = 2 * b * d * 4 + 2 * 8 * b * 4 + 512 * d * 4
+    # per bank row: one one-hot row (B floats, double-buffered compute)
     # + ~10 streamed (row, D) tiles (5 in + 5 out), double-buffered
-    per_row = 2 * b * 4 + 10 * max(1, dim) * 4 * 2
+    per_row = 2 * b * 4 + 10 * d * 4 * 2
     avail = budget - fixed
     if avail < per_row * 8:
         raise ValueError(

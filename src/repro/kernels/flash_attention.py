@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import resolve_interpret
 
 NEG = -1e30
@@ -109,7 +108,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
         scratch_shapes=[pltpu.VMEM((qb, 1), jnp.float32),
                         pltpu.VMEM((qb, 1), jnp.float32),
                         pltpu.VMEM((qb, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -8,14 +8,15 @@ from ``kb_gather`` + ``lazy_apply`` it is still two kernels and an extra
 round-trip of the row block. This kernel streams each (bank, grad_sum,
 grad_cnt, grad_sqnorm) tile HBM->VMEM exactly once and, per tile:
 
-1. builds the one-hot membership of the requested ids in the tile,
+1. builds the (tile rows, B) one-hot membership of the requested ids,
 2. computes the outlier-clipped cached-gradient average (``pending_delta``
    semantics, same formula as ``repro.core.knowledge_bank``),
 3. writes back the updated table tile and zeroed caches for touched rows,
-4. accumulates ``onehot @ updated_tile`` on the MXU into the (B, D) output
+4. accumulates ``onehot^T @ updated_tile`` on the MXU into the (B, D) output
    (the bandwidth-optimal TPU gather — see kb_gather.py).
 
-Grid: bank tiles, sequential; the (B, D) result lives in VMEM scratch.
+Grid: bank tiles, sequential; the (B, D) result block stays resident in
+VMEM across the grid and accumulates every tile's contribution.
 Version counters are (N,) int32 metadata — the caller bumps them with a
 cheap jnp scatter (see ``repro.core.kb_engine.PallasBackend``); fusing them
 here would save nothing measurable against the (N, D) streams.
@@ -33,25 +34,49 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import fused_lookup_block, resolve_interpret
+from repro.kernels.nn_search import HIGHEST
 
 
-def _fused_kernel(ids_ref, tbl_ref, gsum_ref, gcnt_ref, gsq_ref,
+def _membership(idr_ref, j, n_block: int):
+    """The tile's (NB, B) one-hot of the padded ids (a (1, B) row) and its
+    (NB, 1) per-row ``touched`` flag, reduced over lanes — reshaping a
+    (NB,) bool reduction into a column is not lowerable on TPU."""
+    ids = idr_ref[...]                                      # (1, B)
+    rows = j * n_block + jax.lax.broadcasted_iota(
+        jnp.int32, (n_block, ids.shape[1]), 0)
+    hits = (ids == rows).astype(jnp.float32)                # (NB, B)
+    touched = jnp.max(hits, axis=1, keepdims=True) > 0      # (NB, 1)
+    return hits, touched
+
+
+# ids per gather matmul: bounds the (chunk, D) product the MXU writes
+# before it is added into the resident output block
+GATHER_CHUNK = 512
+
+
+def _accumulate_rows(o_ref, hits, rows):
+    """o_ref (B, D) += hits^T @ rows on the MXU, GATHER_CHUNK ids at a time
+    and at full fp32 precision: a one-hot gather must return the stored
+    rows bit-for-bit."""
+    B = o_ref.shape[0]
+    for lo in range(0, B, GATHER_CHUNK):
+        n = min(GATHER_CHUNK, B - lo)
+        o_ref[lo:lo + n, :] += jax.lax.dot_general(
+            hits[:, lo:lo + n], rows, (((0,), (0,)), ((), ())),
+            precision=HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _fused_kernel(idr_ref, tbl_ref, gsum_ref, gcnt_ref, gsq_ref,
                   o_tbl_ref, o_gsum_ref, o_gcnt_ref, o_gsq_ref, o_vals_ref,
-                  acc_ref, *, n_block: int, lazy_lr: float, zmax: float):
+                  *, n_block: int, lazy_lr: float, zmax: float):
     j = pl.program_id(0)
 
     @pl.when(j == 0)
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_vals_ref[...] = jnp.zeros_like(o_vals_ref)
 
-    ids = ids_ref[...]                                      # (B,)
-    base = j * n_block
-    rows = base + jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], n_block), 1)
-    onehot = (ids[:, None] == rows).astype(jnp.float32)     # (B, NB)
-    touched = (jnp.sum(onehot, axis=0) > 0)[:, None]        # (NB, 1)
+    hits, touched = _membership(idr_ref, j, n_block)
 
     tbl = tbl_ref[...].astype(jnp.float32)                  # (NB, D)
     gsum = gsum_ref[...]
@@ -72,13 +97,7 @@ def _fused_kernel(ids_ref, tbl_ref, gsum_ref, gcnt_ref, gsq_ref,
     o_gsum_ref[...] = jnp.where(touched, 0.0, gsum)
     o_gcnt_ref[...] = jnp.where(touched, 0.0, gcnt)
     o_gsq_ref[...] = jnp.where(touched, 0.0, gsq)
-    acc_ref[...] += jax.lax.dot_general(
-        onehot, new_tbl, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == pl.num_programs(0) - 1)
-    def _():
-        o_vals_ref[...] = acc_ref[...]
+    _accumulate_rows(o_vals_ref, hits, new_tbl)
 
 
 def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
@@ -91,8 +110,8 @@ def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
     new_grad_sqnorm) — ``kb_lookup(..., apply_pending=True)`` semantics for
     everything except the version counter (bumped by the caller).
     ``interpret``/``n_block`` default to the process `KernelConfig`
-    (repro.env); the bank tile shrinks with the batch so the (B, n_block)
-    one-hot + (B, D) accumulator stay inside the VMEM budget (legal tiles
+    (repro.env); the bank tile shrinks with the batch so the (n_block, B)
+    one-hot + resident (B, D) output stay inside the VMEM budget (legal tiles
     for serving batches > 4k ids)."""
     interpret = resolve_interpret(interpret)
     N, D = table.shape
@@ -111,7 +130,7 @@ def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
     out = pl.pallas_call(
         kern,
         grid=(Np // nb,),
-        in_specs=[pl.BlockSpec((Bp,), lambda j: (0,)),
+        in_specs=[pl.BlockSpec((1, Bp), lambda j: (0, 0)),
                   pl.BlockSpec((nb, D), lambda j: (j, 0)),
                   pl.BlockSpec((nb, D), lambda j: (j, 0)),
                   pl.BlockSpec((nb, 1), lambda j: (j, 0)),
@@ -126,11 +145,11 @@ def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Bp, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((Bp, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(idp, pad(table), pad(grad_sum), pad(cnt2), pad(sq2))
+    )(idp[None, :], pad(table), pad(grad_sum), pad(cnt2),
+      pad(sq2))
     new_tbl, gsum, gcnt, gsq, vals = out
     return (vals[:B], new_tbl[:N], gsum[:N], gcnt[:N, 0], gsq[:N, 0])
 
@@ -139,9 +158,9 @@ def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
 # quantized variant: int8 codes + per-row (scale, offset), dequant fused
 # ---------------------------------------------------------------------------
 
-def _fused_kernel_q(ids_ref, tbl_ref, scl_ref, off_ref, gsum_ref, gcnt_ref,
+def _fused_kernel_q(idr_ref, tbl_ref, scl_ref, off_ref, gsum_ref, gcnt_ref,
                     gsq_ref, o_tbl_ref, o_scl_ref, o_off_ref, o_gsum_ref,
-                    o_gcnt_ref, o_gsq_ref, o_vals_ref, acc_ref, *,
+                    o_gcnt_ref, o_gsq_ref, o_vals_ref, *,
                     n_block: int, lazy_lr: float, zmax: float):
     """The fused lookup over an int8-coded bank: dequantize the tile in
     VMEM, apply the clipped cached-gradient average, RE-quantize the rows
@@ -153,14 +172,9 @@ def _fused_kernel_q(ids_ref, tbl_ref, scl_ref, off_ref, gsum_ref, gcnt_ref,
 
     @pl.when(j == 0)
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        o_vals_ref[...] = jnp.zeros_like(o_vals_ref)
 
-    ids = ids_ref[...]                                      # (B,)
-    base = j * n_block
-    rows = base + jax.lax.broadcasted_iota(
-        jnp.int32, (ids.shape[0], n_block), 1)
-    onehot = (ids[:, None] == rows).astype(jnp.float32)     # (B, NB)
-    touched = (jnp.sum(onehot, axis=0) > 0)[:, None]        # (NB, 1)
+    hits, touched = _membership(idr_ref, j, n_block)
 
     codes = tbl_ref[...].astype(jnp.float32)                # (NB, D)
     scl = scl_ref[...]                                      # (NB, 1)
@@ -197,13 +211,7 @@ def _fused_kernel_q(ids_ref, tbl_ref, scl_ref, off_ref, gsum_ref, gcnt_ref,
     o_gsum_ref[...] = jnp.where(touched, 0.0, gsum)
     o_gcnt_ref[...] = jnp.where(touched, 0.0, gcnt)
     o_gsq_ref[...] = jnp.where(touched, 0.0, gsq)
-    acc_ref[...] += jax.lax.dot_general(
-        onehot, codes_w * scl_w + off_w, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-
-    @pl.when(j == pl.num_programs(0) - 1)
-    def _():
-        o_vals_ref[...] = acc_ref[...]
+    _accumulate_rows(o_vals_ref, hits, codes_w * scl_w + off_w)
 
 
 def kb_fused_lookup_q_pallas(table, qscale, qoffset, grad_sum, grad_cnt,
@@ -239,7 +247,7 @@ def kb_fused_lookup_q_pallas(table, qscale, qoffset, grad_sum, grad_cnt,
     out = pl.pallas_call(
         kern,
         grid=(Np // nb,),
-        in_specs=[pl.BlockSpec((Bp,), lambda j: (0,)),
+        in_specs=[pl.BlockSpec((1, Bp), lambda j: (0, 0)),
                   row2, col2, col2, row2, col2, col2],
         out_specs=[row2, col2, col2, row2, col2, col2,
                    pl.BlockSpec((Bp, D), lambda j: (0, 0))],
@@ -250,11 +258,10 @@ def kb_fused_lookup_q_pallas(table, qscale, qoffset, grad_sum, grad_cnt,
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Bp, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((Bp, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(idp, pad(table), sclp, pad(qoffset[:, None]), pad(grad_sum),
+    )(idp[None, :], pad(table), sclp, pad(qoffset[:, None]), pad(grad_sum),
       pad(grad_cnt[:, None]), pad(grad_sqnorm[:, None]))
     new_tbl, scl, off, gsum, gcnt, gsq, vals = out
     return (vals[:B], new_tbl[:N], scl[:N, 0], off[:N, 0], gsum[:N],

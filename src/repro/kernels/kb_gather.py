@@ -18,8 +18,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import fit_block_rows, kernel_config, resolve_interpret
+from repro.kernels.nn_search import HIGHEST
 
 
 def _gather_kernel(ids_ref, bank_ref, o_ref, acc_ref, *, n_block: int):
@@ -29,14 +29,14 @@ def _gather_kernel(ids_ref, bank_ref, o_ref, acc_ref, *, n_block: int):
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ids = ids_ref[...]                                     # (IB,)
+    ids = ids_ref[...]                                     # (IB, 1)
     base = nb * n_block
     rows = base + jax.lax.broadcasted_iota(
         jnp.int32, (ids.shape[0], n_block), 1)
-    onehot = (ids[:, None] == rows).astype(jnp.float32)    # (IB, NB)
+    onehot = (ids == rows).astype(jnp.float32)             # (IB, NB)
     bank = bank_ref[...].astype(jnp.float32)               # (NB, D)
     acc_ref[...] += jax.lax.dot_general(
-        onehot, bank, (((1,), (0,)), ((), ())),
+        onehot, bank, (((1,), (0,)), ((), ())), precision=HIGHEST,
         preferred_element_type=jnp.float32)
 
     @pl.when(nb == pl.num_programs(1) - 1)
@@ -54,7 +54,7 @@ def kb_gather_pallas(table, ids, *, id_block: int = 256,
     interpret = resolve_interpret(interpret)
     N, D = table.shape
     B = ids.shape[0]
-    ib = min(id_block, B)
+    ib = min(id_block, -(-B // 8) * 8)
     if n_block is None:
         # one-hot is (ib, nb): charge ib floats per bank row on top of the
         # streamed (nb, D) tile
@@ -69,13 +69,13 @@ def kb_gather_pallas(table, ids, *, id_block: int = 256,
     out = pl.pallas_call(
         functools.partial(_gather_kernel, n_block=nb),
         grid=grid,
-        in_specs=[pl.BlockSpec((ib,), lambda i, j: (i,)),
+        in_specs=[pl.BlockSpec((ib, 1), lambda i, j: (i, 0)),
                   pl.BlockSpec((nb, D), lambda i, j: (j, 0))],
         out_specs=pl.BlockSpec((ib, D), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, D), table.dtype),
         scratch_shapes=[pltpu.VMEM((ib, D), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(idp, tp)
+    )(idp[:, None], tp)
     return out[:B]

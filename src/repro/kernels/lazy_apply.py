@@ -17,7 +17,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import fit_block_rows, resolve_interpret
 
 
@@ -76,7 +75,7 @@ def lazy_apply_pallas(table, grad_sum, grad_cnt, grad_sqnorm, *,
                    jax.ShapeDtypeStruct((Np, D), jnp.float32),
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32),
                    jax.ShapeDtypeStruct((Np, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(pad(table), pad(grad_sum), pad(cnt2), pad(sq2))

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import resolve_interpret
 
 
@@ -76,7 +75,7 @@ def mamba_scan_pallas(delta, bm, cm, x, A, *, di_block: int = 512,
         out_specs=pl.BlockSpec((1, sb, db), lambda b, d, s: (b, s, d)),
         out_shape=jax.ShapeDtypeStruct((B, S, di), jnp.float32),
         scratch_shapes=[pltpu.VMEM((db, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(delta, bm, cm, x, A)

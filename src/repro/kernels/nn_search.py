@@ -22,10 +22,12 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import fit_block_rows, resolve_interpret
 
 NEG = -1e30
+# the bank is an fp32 store: every score and one-hot-gather matmul runs at
+# full fp32 precision (the TPU default rounds f32 operands through bf16)
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def overfetch_exclude_topk(search, n_rows: int, k: int, exclude_ids):
@@ -49,24 +51,42 @@ def overfetch_exclude_topk(search, n_rows: int, k: int, exclude_ids):
 
 def _merge_topk(scores, ids, best_s, best_i, k: int):
     """scores/ids: (QB, M) candidates; best_s/best_i: (QB, k) running.
-    Returns updated (best_s, best_i). Ties prefer lower id (stable)."""
-    all_s = jnp.concatenate([best_s, scores], axis=1)
-    all_i = jnp.concatenate([best_i, ids], axis=1)
-    out_s, out_i = [], []
-    for _ in range(k):
+    Returns updated (best_s, best_i). Ties prefer lower id (stable).
+
+    Equivalent to k rounds of argmax over ``concat([best, candidates])``
+    with the lowest-id tie-break, masking the FIRST selected column each
+    round. The two halves stay separate arrays (the running top-k comes
+    first in that column order) and every step is an elementwise op or a
+    lane reduction, which is what the TPU kernel lowering supports."""
+    big = jnp.iinfo(jnp.int32).max
+    col_b = jax.lax.broadcasted_iota(jnp.int32, best_s.shape, 1)
+    col_c = jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    out_s = jnp.full(best_s.shape, NEG, jnp.float32)
+    out_i = jnp.full(best_i.shape, big, jnp.int32)
+    for t in range(k):
         # argmax with lowest-id tie-break: order by (score, -id)
-        m = jnp.max(all_s, axis=1, keepdims=True)
-        is_max = all_s >= m
-        cand_id = jnp.where(is_max, all_i, jnp.iinfo(jnp.int32).max)
-        sel_id = jnp.min(cand_id, axis=1, keepdims=True)
-        sel = is_max & (all_i == sel_id)
-        # take the first selected column
-        first = jnp.cumsum(sel.astype(jnp.int32), axis=1) == 1
-        sel = sel & first
-        out_s.append(m[:, 0])
-        out_i.append(sel_id[:, 0])
-        all_s = jnp.where(sel, NEG, all_s)
-    return jnp.stack(out_s, 1), jnp.stack(out_i, 1)
+        m = jnp.maximum(jnp.max(best_s, axis=1, keepdims=True),
+                        jnp.max(scores, axis=1, keepdims=True))
+        max_b = best_s >= m
+        max_c = scores >= m
+        sel_id = jnp.minimum(
+            jnp.min(jnp.where(max_b, best_i, big), axis=1, keepdims=True),
+            jnp.min(jnp.where(max_c, ids, big), axis=1, keepdims=True))
+        sel_b = max_b & (best_i == sel_id)
+        sel_c = max_c & (ids == sel_id)
+        # mask only the first selected column: a running-top-k column
+        # precedes every candidate column
+        first_b = jnp.min(jnp.where(sel_b, col_b, big), axis=1,
+                          keepdims=True)
+        first_c = jnp.min(jnp.where(sel_c, col_c, big), axis=1,
+                          keepdims=True)
+        take_b = sel_b & (col_b == first_b)
+        take_c = sel_c & (col_c == first_c) & (first_b == big)
+        out_s = jnp.where(col_b == t, m, out_s)
+        out_i = jnp.where(col_b == t, sel_id, out_i)
+        best_s = jnp.where(take_b, NEG, best_s)
+        scores = jnp.where(take_c, NEG, scores)
+    return out_s, out_i
 
 
 def _nn_kernel(q_ref, bank_ref, os_ref, oi_ref, bs_ref, bi_ref, *, k: int,
@@ -81,6 +101,7 @@ def _nn_kernel(q_ref, bank_ref, os_ref, oi_ref, bs_ref, bi_ref, *, k: int,
     q = q_ref[...].astype(jnp.float32)                    # (QB, D)
     b = bank_ref[...].astype(jnp.float32)                 # (NB, D)
     scores = jax.lax.dot_general(q, b, (((1,), (1,)), ((), ())),
+                                 precision=HIGHEST,
                                  preferred_element_type=jnp.float32)
     base = nb * nb_block
     ids = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
@@ -128,7 +149,7 @@ def nn_search_pallas(queries, bank, k: int, *, q_block: int = 128,
                    jax.ShapeDtypeStruct((Bp, k), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((qb, k), jnp.float32),
                         pltpu.VMEM((qb, k), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(qp, bp)

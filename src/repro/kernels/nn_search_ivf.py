@@ -52,9 +52,8 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import resolve_interpret
-from repro.kernels.nn_search import NEG, _merge_topk
+from repro.kernels.nn_search import HIGHEST, NEG, _merge_topk
 
 _IMAX = jnp.iinfo(jnp.int32).max
 
@@ -109,7 +108,8 @@ def ivf_probes(queries, centroids, nprobe: int):
     """Top-``nprobe`` partitions per query by centroid inner product.
     queries: (B, D); centroids: (C, D) -> (B, nprobe) int32."""
     nprobe = min(nprobe, centroids.shape[0])
-    scores = queries.astype(jnp.float32) @ centroids.T.astype(jnp.float32)
+    scores = jnp.matmul(queries.astype(jnp.float32),
+                        centroids.T.astype(jnp.float32), precision=HIGHEST)
     _, probes = jax.lax.top_k(scores, nprobe)
     return probes.astype(jnp.int32)
 
@@ -124,7 +124,8 @@ def _rerank_live(table, queries, ids):
     n = table.shape[0]
     valid = (ids >= 0) & (ids < n)
     rows = table[jnp.where(valid, ids, 0)].astype(jnp.float32)   # (B, k, D)
-    s = jnp.einsum("bd,bkd->bk", queries.astype(jnp.float32), rows)
+    s = jnp.einsum("bd,bkd->bk", queries.astype(jnp.float32), rows,
+                   precision=HIGHEST)
     s = jnp.where(valid, s, -jnp.inf)
     order = jnp.argsort(-s, axis=-1)
     return (jnp.take_along_axis(s, order, axis=1),
@@ -140,7 +141,8 @@ def _rerank_live_q(codes, qscale, qoffset, queries, ids):
     safe = jnp.where(valid, ids, 0)
     rows = (codes[safe].astype(jnp.float32) * qscale[safe][..., None]
             + qoffset[safe][..., None])                          # (B, k, D)
-    s = jnp.einsum("bd,bkd->bk", queries.astype(jnp.float32), rows)
+    s = jnp.einsum("bd,bkd->bk", queries.astype(jnp.float32), rows,
+                   precision=HIGHEST)
     s = jnp.where(valid, s, -jnp.inf)
     order = jnp.argsort(-s, axis=-1)
     return (jnp.take_along_axis(s, order, axis=1),
@@ -150,37 +152,146 @@ def _rerank_live_q(codes, qscale, qoffset, queries, ids):
 # ---------------------------------------------------------------------------
 # stage 2, Pallas: scalar-prefetched bucket tiles + running top-k
 # ---------------------------------------------------------------------------
+#
+# One kernel serves the dense, quantized and sharded layouts. Grid
+# (S, B, chunks per shard): shard s of query i merges the chunk
+# ``sel[i, s*cps + r]`` into a running top-k, resets it at r == 0 and
+# flushes it at the shard's last chunk. The dense index is the S == 1 case.
+#
+# TPU tiling: every block's second-minor dimension is a multiple of 8. So
+# queries are fetched as the (8, D) row group holding query i, packed ids
+# and int8 scale/offset as (8, LB) groups of chunk rows (a (C*cap/LB, LB)
+# view), and results are written back as (8, k) row groups; the kernel
+# picks or replaces its one row with a sublane mask. Row groups are
+# revisited on consecutive steps, so the query axis runs "arbitrary".
 
-def _ivf_kernel(sel_ref, nv_ref, q_ref, vec_ref, id_ref, os_ref, oi_ref,
-                bs_ref, bi_ref, *, k: int):
-    del sel_ref                       # consumed by the index_maps
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+_SUB = 8
+_SMEM_TABLE_BYTES = 512 * 1024
 
-    @pl.when(j == 0)
+
+def _pick_row(x, r):
+    """Row ``r`` of an (8, L) tile as (1, L), by a masked sublane sum."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    return jnp.sum(jnp.where(rows == r, x, jnp.zeros_like(x)), axis=0,
+                   keepdims=True)
+
+
+def _put_row(ref, r, val):
+    """Overwrite row ``r`` of an (8, L) output block with ``val`` (1, L)."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, ref.shape, 0)
+    ref[...] = jnp.where(rows == r, val, ref[...])
+
+
+def _ivf_kernel(sel_ref, nv_ref, q_ref, vec_ref, *refs, k: int, cps: int,
+                quantized: bool):
+    """Merge one bucket chunk into query i's running top-k for shard s.
+    Int8 tiles are never dequantized: scores are ``s * (q . c) + o *
+    sum(q)``, the exact decomposition of q against the dequantized rows,
+    fused into the MXU dot + one VPU fixup."""
+    if quantized:
+        scl_ref, off_ref, id_ref, os_ref, oi_ref, bs_ref, bi_ref = refs
+    else:
+        id_ref, os_ref, oi_ref, bs_ref, bi_ref = refs
+    s = pl.program_id(0)
+    i = pl.program_id(1)
+    r = pl.program_id(2)
+
+    @pl.when(r == 0)
     def _():
         bs_ref[...] = jnp.full_like(bs_ref, NEG)
         bi_ref[...] = jnp.full_like(bi_ref, _IMAX)
 
     # merge only this query's occupied chunks (ivf_chunk_plan); past-valid
     # steps re-see the last fetched block and skip the work entirely
-    @pl.when(j < nv_ref[i])
+    @pl.when(r < nv_ref[i, s])
     def _():
-        q = q_ref[...].astype(jnp.float32)                   # (1, D)
-        v = vec_ref[...].astype(jnp.float32)                 # (LB, D)
-        ids = id_ref[...].reshape(1, -1)                     # (1, LB)
+        row = sel_ref[i, s * cps + r] % _SUB
+        q = _pick_row(q_ref[...].astype(jnp.float32), i % _SUB)  # (1, D)
+        v = vec_ref[...].astype(jnp.float32)                     # (LB, D)
         scores = jax.lax.dot_general(q, v, (((1,), (1,)), ((), ())),
+                                     precision=HIGHEST,
                                      preferred_element_type=jnp.float32)
+        if quantized:
+            scores = (scores * _pick_row(scl_ref[...], row)
+                      + jnp.sum(q) * _pick_row(off_ref[...], row))
+        ids = _pick_row(id_ref[...], row)                        # (1, LB)
         scores = jnp.where(ids >= 0, scores, NEG)
         ids = jnp.where(ids >= 0, ids, _IMAX)
         bs, bi = _merge_topk(scores, ids, bs_ref[...], bi_ref[...], k)
         bs_ref[...] = bs
         bi_ref[...] = bi
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(r == cps - 1)
     def _():
-        os_ref[...] = bs_ref[...]
-        oi_ref[...] = bi_ref[...]
+        _put_row(os_ref, i % _SUB, bs_ref[...])
+        _put_row(oi_ref, i % _SUB, bi_ref[...])
+
+
+def _stage2_call(sel, nvalid, queries, vecs, ids, k: int, *, lb: int,
+                 scale=None, offset=None, interpret: bool):
+    """Run ``_ivf_kernel`` over a chunk schedule. sel: (B, S*cps) chunk
+    indices; nvalid: (B, S) chunks to merge per (query, shard); vecs:
+    (rows, D) packed rows; ids (and int8 ``scale``/``offset``): (rows,).
+    Returns (scores, ids), each (B, S, k).
+
+    The schedule is scalar-prefetched into SMEM (1 MiB on a v5e core), so
+    a batch whose table exceeds ``_SMEM_TABLE_BYTES`` runs as several
+    calls over row groups of queries."""
+    B, D = queries.shape
+    per_query = 4 * (sel.shape[1] + nvalid.shape[1])
+    qb = max(_SUB, _SMEM_TABLE_BYTES // per_query // _SUB * _SUB)
+    if B > qb:
+        parts = [_stage2_call(sel[lo:lo + qb], nvalid[lo:lo + qb],
+                              queries[lo:lo + qb], vecs, ids, k, lb=lb,
+                              scale=scale, offset=offset,
+                              interpret=interpret)
+                 for lo in range(0, B, qb)]
+        return tuple(jnp.concatenate(p, axis=0) for p in zip(*parts))
+    S = nvalid.shape[1]
+    cps = sel.shape[1] // S
+    Bp = -(-B // _SUB) * _SUB
+    # padded queries merge nothing (nvalid 0) and read chunk 0
+    sel = jnp.pad(sel, ((0, Bp - B), (0, 0)))
+    nvalid = jnp.pad(nvalid, ((0, Bp - B), (0, 0)))
+    queries = jnp.pad(queries, ((0, Bp - B), (0, 0)))
+
+    def chunk_rows(a, fill):
+        a = a.reshape(-1, lb)
+        n = a.shape[0]
+        return jnp.pad(a, ((0, -(-n // _SUB) * _SUB - n), (0, 0)),
+                       constant_values=fill)
+
+    quantized = scale is not None
+    per_slot = ([chunk_rows(scale, 1.0), chunk_rows(offset, 0.0)]
+                if quantized else []) + [chunk_rows(ids, -1)]
+    chunk = lambda s, i, r, sel: sel[i, s * cps + r]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, Bp, cps),
+        in_specs=[
+            pl.BlockSpec((_SUB, D),
+                         lambda s, i, r, sel, nv: (i // _SUB, 0)),
+            pl.BlockSpec((lb, D),
+                         lambda s, i, r, sel, nv: (chunk(s, i, r, sel), 0)),
+        ] + [pl.BlockSpec((_SUB, lb), lambda s, i, r, sel, nv:
+                          (chunk(s, i, r, sel) // _SUB, 0))] * len(per_slot),
+        out_specs=[pl.BlockSpec((pl.Squeezed(), _SUB, k),
+                                lambda s, i, r, sel, nv: (s, i // _SUB, 0))
+                   ] * 2,
+        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32),
+                        pltpu.VMEM((1, k), jnp.int32)],
+    )
+    out_s, out_i = pl.pallas_call(
+        functools.partial(_ivf_kernel, k=k, cps=cps, quantized=quantized),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((S, Bp, k), jnp.float32),
+                   jax.ShapeDtypeStruct((S, Bp, k), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(sel, nvalid, queries, vecs, *per_slot)
+    return (jnp.transpose(out_s[:, :B], (1, 0, 2)),
+            jnp.transpose(out_i[:, :B], (1, 0, 2)))
 
 
 def ivf_stage2_pallas(packed_vecs, packed_ids, queries, probes, k: int, *,
@@ -190,38 +301,15 @@ def ivf_stage2_pallas(packed_vecs, packed_ids, queries, probes, k: int, *,
     probes: (B, nprobe) -> (scores (B, k), ids (B, k)), snapshot scores.
     ``bucket_occ`` (C,) enables the occupied-chunks-only schedule (see
     ``ivf_chunk_plan``) — same results, work proportional to occupancy."""
-    interpret = resolve_interpret(interpret)
-    B, D = queries.shape
-    nprobe = probes.shape[1]
     lb = _chunk_rows(bucket_cap, block)
     assert bucket_cap % lb == 0, (bucket_cap, lb)
-    cpb = bucket_cap // lb                      # chunks per bucket
-    n_chunks = nprobe * cpb
     # block-selector table + per-query valid count: chunk j of query i
     # reads packed block sel[i, j], merging only while j < nvalid[i]
-    sel, nvalid = ivf_chunk_plan(probes, bucket_occ, cpb, lb)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda i, j, sel, nv: (i, 0)),
-            pl.BlockSpec((lb, D), lambda i, j, sel, nv: (sel[i, j], 0)),
-            pl.BlockSpec((lb,), lambda i, j, sel, nv: (sel[i, j],)),
-        ],
-        out_specs=[pl.BlockSpec((1, k), lambda i, j, sel, nv: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i, j, sel, nv: (i, 0))],
-        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32),
-                        pltpu.VMEM((1, k), jnp.int32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_ivf_kernel, k=k),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, k), jnp.float32),
-                   jax.ShapeDtypeStruct((B, k), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(sel, nvalid, queries, packed_vecs, packed_ids)
+    sel, nvalid = ivf_chunk_plan(probes, bucket_occ, bucket_cap // lb, lb)
+    s, i = _stage2_call(sel, nvalid[:, None], queries, packed_vecs,
+                        packed_ids, k, lb=lb,
+                        interpret=resolve_interpret(interpret))
+    return s[:, 0], i[:, 0]
 
 
 def ivf_search_pallas(table, centroids, packed_vecs, packed_ids, queries,
@@ -241,43 +329,6 @@ def ivf_search_pallas(table, centroids, packed_vecs, packed_ids, queries,
 # stage 2, Pallas, quantized: int8 bucket tiles with fused dequant scoring
 # ---------------------------------------------------------------------------
 
-def _ivf_kernel_q(sel_ref, nv_ref, q_ref, vec_ref, scl_ref, off_ref, id_ref,
-                  os_ref, oi_ref, bs_ref, bi_ref, *, k: int):
-    """The stage-2 merge over int8 bucket tiles. Never dequantizes the
-    (LB, D) tile: scores via ``s * (q . c) + o * sum(q)`` — the exact
-    decomposition of q against the dequantized rows, fused into the MXU
-    dot + one VPU fixup."""
-    del sel_ref
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        bs_ref[...] = jnp.full_like(bs_ref, NEG)
-        bi_ref[...] = jnp.full_like(bi_ref, _IMAX)
-
-    @pl.when(j < nv_ref[i])
-    def _():
-        q = q_ref[...].astype(jnp.float32)                   # (1, D)
-        c = vec_ref[...].astype(jnp.float32)                 # (LB, D) codes
-        scl = scl_ref[...].reshape(1, -1)                    # (1, LB)
-        off = off_ref[...].reshape(1, -1)
-        ids = id_ref[...].reshape(1, -1)                     # (1, LB)
-        raw = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        scores = raw * scl + jnp.sum(q) * off
-        scores = jnp.where(ids >= 0, scores, NEG)
-        ids = jnp.where(ids >= 0, ids, _IMAX)
-        bs, bi = _merge_topk(scores, ids, bs_ref[...], bi_ref[...], k)
-        bs_ref[...] = bs
-        bi_ref[...] = bi
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _():
-        os_ref[...] = bs_ref[...]
-        oi_ref[...] = bi_ref[...]
-
-
 def ivf_stage2_quantized_pallas(packed_codes, packed_scale, packed_offset,
                                 packed_ids, queries, probes, k: int, *,
                                 bucket_cap: int, bucket_occ=None,
@@ -286,38 +337,14 @@ def ivf_stage2_quantized_pallas(packed_codes, packed_scale, packed_offset,
     """``ivf_stage2_pallas`` over a quantized index: packed_codes
     (C*cap, D) int8, packed_scale/packed_offset (C*cap,) f32. Snapshot
     scores are exact w.r.t. the quantized rows."""
-    interpret = resolve_interpret(interpret)
-    B, D = queries.shape
-    nprobe = probes.shape[1]
     lb = _chunk_rows(bucket_cap, block)
     assert bucket_cap % lb == 0, (bucket_cap, lb)
-    cpb = bucket_cap // lb
-    n_chunks = nprobe * cpb
-    sel, nvalid = ivf_chunk_plan(probes, bucket_occ, cpb, lb)
-    flat = pl.BlockSpec((lb,), lambda i, j, sel, nv: (sel[i, j],))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda i, j, sel, nv: (i, 0)),
-            pl.BlockSpec((lb, D), lambda i, j, sel, nv: (sel[i, j], 0)),
-            flat, flat, flat,
-        ],
-        out_specs=[pl.BlockSpec((1, k), lambda i, j, sel, nv: (i, 0)),
-                   pl.BlockSpec((1, k), lambda i, j, sel, nv: (i, 0))],
-        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32),
-                        pltpu.VMEM((1, k), jnp.int32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_ivf_kernel_q, k=k),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, k), jnp.float32),
-                   jax.ShapeDtypeStruct((B, k), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(sel, nvalid, queries, packed_codes, packed_scale, packed_offset,
-      packed_ids)
+    sel, nvalid = ivf_chunk_plan(probes, bucket_occ, bucket_cap // lb, lb)
+    s, i = _stage2_call(sel, nvalid[:, None], queries, packed_codes,
+                        packed_ids, k, lb=lb, scale=packed_scale,
+                        offset=packed_offset,
+                        interpret=resolve_interpret(interpret))
+    return s[:, 0], i[:, 0]
 
 
 def ivf_search_quantized_pallas(table_codes, qscale, qoffset, centroids,
@@ -341,43 +368,6 @@ def ivf_search_quantized_pallas(table_codes, qscale, qoffset, centroids,
 # stage 2, Pallas, sharded: per-shard shortlists in one grid
 # ---------------------------------------------------------------------------
 
-def _ivf_kernel_sharded(sel_ref, nv_ref, q_ref, vec_ref, id_ref,
-                        os_ref, oi_ref, bs_ref, bi_ref, *, k: int,
-                        chunks_per_shard: int):
-    """The dense stage-2 kernel walked shard-major: grid axis 1 covers
-    every shard's chunks back to back; the running top-k scratch resets at
-    each shard's first chunk and flushes to that shard's (1, 1, k) output
-    slot at its last — per-(query, shard) shortlists in ONE pallas_call."""
-    del sel_ref
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    r = j % chunks_per_shard                 # chunk step within the shard
-    s = j // chunks_per_shard
-
-    @pl.when(r == 0)
-    def _():
-        bs_ref[...] = jnp.full_like(bs_ref, NEG)
-        bi_ref[...] = jnp.full_like(bi_ref, _IMAX)
-
-    @pl.when(r < nv_ref[i, s])
-    def _():
-        q = q_ref[...].astype(jnp.float32)                   # (1, D)
-        v = vec_ref[...].astype(jnp.float32)                 # (LB, D)
-        ids = id_ref[...].reshape(1, -1)                     # (1, LB)
-        scores = jax.lax.dot_general(q, v, (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-        scores = jnp.where(ids >= 0, scores, NEG)
-        ids = jnp.where(ids >= 0, ids, _IMAX)
-        bs, bi = _merge_topk(scores, ids, bs_ref[...], bi_ref[...], k)
-        bs_ref[...] = bs
-        bi_ref[...] = bi
-
-    @pl.when(r == chunks_per_shard - 1)
-    def _():
-        os_ref[...] = bs_ref[...].reshape(os_ref.shape)
-        oi_ref[...] = bi_ref[...].reshape(oi_ref.shape)
-
-
 def ivf_stage2_sharded_pallas(packed_vecs, packed_ids, queries, probes,
                               k: int, *, n_shards: int, nlist: int,
                               bucket_cap: int, bucket_occ=None,
@@ -390,45 +380,21 @@ def ivf_stage2_sharded_pallas(packed_vecs, packed_ids, queries, probes,
     snapshot scores, global ids (the packed ids are global), NEG/_IMAX in
     unfilled slots. ``bucket_occ`` (S*C,) enables the occupied-chunk
     schedule per shard, exactly as in the dense kernel."""
-    interpret = resolve_interpret(interpret)
-    B, D = queries.shape
+    B = queries.shape[0]
     S, nprobe = probes.shape[1], probes.shape[2]
     lb = _chunk_rows(bucket_cap, block)
     assert bucket_cap % lb == 0, (bucket_cap, lb)
     cpb = bucket_cap // lb
-    cps = nprobe * cpb                       # chunks per shard
     # globalize the bucket ids (shard s, local b -> s*nlist + b), then the
     # dense chunk planner runs unchanged on the flattened (B*S, nprobe)
     gprobes = (probes.astype(jnp.int32) +
                (jnp.arange(S, dtype=jnp.int32) * nlist)[None, :, None])
     sel, nvalid = ivf_chunk_plan(gprobes.reshape(B * S, nprobe),
                                  bucket_occ, cpb, lb)
-    sel = sel.reshape(B, S * cps)
-    nvalid = nvalid.reshape(B, S)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, S * cps),
-        in_specs=[
-            pl.BlockSpec((1, D), lambda i, j, sel, nv: (i, 0)),
-            pl.BlockSpec((lb, D), lambda i, j, sel, nv: (sel[i, j], 0)),
-            pl.BlockSpec((lb,), lambda i, j, sel, nv: (sel[i, j],)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda i, j, sel, nv: (i, j // cps, 0)),
-            pl.BlockSpec((1, 1, k), lambda i, j, sel, nv: (i, j // cps, 0)),
-        ],
-        scratch_shapes=[pltpu.VMEM((1, k), jnp.float32),
-                        pltpu.VMEM((1, k), jnp.int32)],
-    )
-    return pl.pallas_call(
-        functools.partial(_ivf_kernel_sharded, k=k, chunks_per_shard=cps),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, S, k), jnp.float32),
-                   jax.ShapeDtypeStruct((B, S, k), jnp.int32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(sel, nvalid, queries, packed_vecs, packed_ids)
+    return _stage2_call(sel.reshape(B, S * nprobe * cpb),
+                        nvalid.reshape(B, S), queries, packed_vecs,
+                        packed_ids, k, lb=lb,
+                        interpret=resolve_interpret(interpret))
 
 
 def ivf_search_sharded_pallas(table, centroids, packed_vecs, packed_ids,
@@ -449,7 +415,8 @@ def ivf_search_sharded_pallas(table, centroids, packed_vecs, packed_ids,
     nprobe = min(nprobe, C)
     qf = queries.astype(jnp.float32)
     cent = centroids.reshape(S, C, D)
-    cscore = jnp.einsum("bd,scd->bsc", qf, cent.astype(jnp.float32))
+    cscore = jnp.einsum("bd,scd->bsc", qf, cent.astype(jnp.float32),
+                        precision=HIGHEST)
     _, probes = jax.lax.top_k(cscore, nprobe)               # (B, S, nprobe)
     ls, li = ivf_stage2_sharded_pallas(
         packed_vecs, packed_ids, queries, probes.astype(jnp.int32), k,
@@ -508,13 +475,15 @@ def ivf_search_sharded_jnp(table, centroids, packed_vecs, packed_ids,
     nprobe = min(nprobe, C)
     qf = queries.astype(jnp.float32)
     cent = centroids.reshape(S, C, D)
-    cscore = jnp.einsum("bd,scd->bsc", qf, cent.astype(jnp.float32))
+    cscore = jnp.einsum("bd,scd->bsc", qf, cent.astype(jnp.float32),
+                        precision=HIGHEST)
     _, probes = jax.lax.top_k(cscore, nprobe)               # (B, S, nprobe)
     sidx = jnp.arange(S)[None, :, None]
     cv = packed_vecs.reshape(S, C, cap, D)[sidx, probes]
     ci = packed_ids.reshape(S, C, cap)[sidx, probes].reshape(B, S, -1)
     s = jnp.einsum("bd,bsld->bsl", qf,
-                   cv.reshape(B, S, nprobe * cap, D).astype(jnp.float32))
+                   cv.reshape(B, S, nprobe * cap, D).astype(jnp.float32),
+                   precision=HIGHEST)
     if packed_scale is not None:
         cs = packed_scale.reshape(S, C, cap)[sidx, probes].reshape(B, S, -1)
         co = packed_offset.reshape(S, C, cap)[sidx, probes].reshape(B, S, -1)
@@ -547,7 +516,7 @@ def ivf_search_jnp(table, centroids, packed_vecs, packed_ids, queries,
     cand_v = packed_vecs.reshape(C, cap, D)[probes].reshape(B, -1, D)
     cand_i = packed_ids.reshape(C, cap)[probes].reshape(B, -1)
     s = jnp.einsum("bd,bld->bl", queries.astype(jnp.float32),
-                   cand_v.astype(jnp.float32))
+                   cand_v.astype(jnp.float32), precision=HIGHEST)
     s = jnp.where(cand_i >= 0, s, NEG)
     L = cand_i.shape[1]
     if L < k:                                   # degenerate tiny index
@@ -576,7 +545,8 @@ def ivf_search_quantized_jnp(table_codes, qscale, qoffset, centroids,
     cand_i = packed_ids.reshape(C, cap)[probes].reshape(B, -1)
     cand_s = packed_scale.reshape(C, cap)[probes].reshape(B, -1)
     cand_o = packed_offset.reshape(C, cap)[probes].reshape(B, -1)
-    s = jnp.einsum("bd,bld->bl", qf, cand_v.astype(jnp.float32))
+    s = jnp.einsum("bd,bld->bl", qf, cand_v.astype(jnp.float32),
+                   precision=HIGHEST)
     s = s * cand_s + jnp.sum(qf, -1, keepdims=True) * cand_o
     s = jnp.where(cand_i >= 0, s, NEG)
     L = cand_i.shape[1]

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 import jax.experimental.pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-from repro.compat import CompilerParams
 from repro.env import resolve_interpret
 
 
@@ -69,7 +68,7 @@ def rwkv_wkv_pallas(r, k, v, w, u, *, seq_block: int = 512,
         out_specs=pl.BlockSpec((1, sb, 1, d), lambda b, h, s: (b, s, h, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, H, d), jnp.float32),
         scratch_shapes=[pltpu.VMEM((d, d), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

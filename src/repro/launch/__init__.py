@@ -1,3 +1,3 @@
-from repro.launch.mesh import make_host_mesh, make_production_mesh
-
-__all__ = ["make_host_mesh", "make_production_mesh"]
+"""Launchers: serve.py, train.py, maker_worker.py and the fleet supervisor.
+Importing the package imports no JAX, so a supervisor that only starts
+processes never holds a chip."""

@@ -177,8 +177,7 @@ def model_flops_analytic(cfg: ModelConfig, shape: InputShape) -> float:
 def analyze(lowered, compiled, cfg: ModelConfig, shape: InputShape,
             dist: DistContext) -> Dict:
     mem = compiled.memory_analysis()
-    from repro.compat import cost_analysis
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     cost = analyze_hlo(compiled.as_text(), dist.num_devices)
     mf = model_flops_analytic(cfg, shape) / dist.num_devices
     rl = roofline_from_cost(cost, model_flops_per_device=mf)

@@ -31,10 +31,18 @@ exit codes plus the restart count.
 
 The connect spec is printed on boot (``fleet ready: --kb-connect ...``) so
 trainers can attach: ``launch/train.py --makers ... --kb-connect <spec>``.
+
+Chips: a chip belongs to one process. On a TPU host every serving process
+(member or standby) gets a chip of its own (``ChipPlan``), and a layout
+with more serving processes than chips fails before anything starts.
+Maker workers are clients of the bank and run pinned to the CPU. With
+``JAX_PLATFORMS=cpu`` set, or no chip on the host, everything runs on the
+CPU. The supervisor itself never imports JAX.
 """
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import re
 import select
@@ -44,6 +52,56 @@ import sys
 import time
 
 STARTUP_TIMEOUT_S = 300         # cold jax import + jit warmup per child
+TPU_PROCESS_PORT_BASE = 8476    # libtpu's own per-process port, + chip
+
+
+def host_chips() -> int:
+    """TPU chips on this host, counted from their device files so the
+    caller never imports JAX; 0 when the caller pinned JAX to the CPU."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+class ChipPlan:
+    """One chip per serving process; client-only processes on the CPU.
+
+    ``serving_env`` hands out the host's chips in order and raises once
+    they run out, so two processes never race for one chip. With no chips
+    every child is pinned to the CPU."""
+
+    def __init__(self, n_chips: int):
+        self.n_chips = n_chips
+        self.assigned = 0
+
+    def require(self, n_serving: int) -> None:
+        """Fail at boot if ``n_serving`` processes cannot each own a chip."""
+        if self.n_chips and n_serving > self.n_chips:
+            raise RuntimeError(
+                f"{n_serving} serving processes but {self.n_chips} chips on "
+                "this host: each needs a chip of its own (shrink the "
+                "layout, or pin the fleet to the CPU with JAX_PLATFORMS=cpu)")
+
+    def serving_env(self, env: dict) -> dict:
+        env = dict(env)
+        if not self.n_chips:
+            env["JAX_PLATFORMS"] = "cpu"
+            return env
+        self.require(self.assigned + 1)
+        chip = self.assigned
+        self.assigned += 1
+        env.update(TPU_VISIBLE_CHIPS=str(chip),
+                   TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_PORT=str(TPU_PROCESS_PORT_BASE + chip))
+        return env
+
+    @staticmethod
+    def client_env(env: dict) -> dict:
+        env = dict(env)
+        env["JAX_PLATFORMS"] = "cpu"
+        return env
 
 
 def _parse_maker_packs(spec: str):
@@ -75,7 +133,8 @@ class Fleet:
         if os.path.isdir(src):
             self.env["PYTHONPATH"] = (src + os.pathsep
                                       + self.env.get("PYTHONPATH", ""))
-        self.env.setdefault("JAX_PLATFORMS", "cpu")
+        self.chips = ChipPlan(host_chips())
+        self.maker_env = ChipPlan.client_env(self.env)
 
     # -- child bootstrapping ----------------------------------------------
 
@@ -93,7 +152,8 @@ class Fleet:
         """Start a serve.py child; return (proc, port) once it reports
         listening — select with a deadline, so a wedged child fails at the
         startup budget with its output attached, not silently."""
-        proc = subprocess.Popen(cmd, env=self.env, stdout=subprocess.PIPE,
+        proc = subprocess.Popen(cmd, env=self.chips.serving_env(self.env),
+                                stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True)
         lines = []
         deadline = time.time() + STARTUP_TIMEOUT_S
@@ -143,6 +203,7 @@ class Fleet:
 
     def start(self):
         a = self.args
+        self.chips.require(a.partitions * (1 + a.replicas))
         for p in range(a.partitions):
             self.members.append(self._boot_server(
                 self._serve_cmd(p, []), f"p{p}"))
@@ -160,7 +221,7 @@ class Fleet:
                 cmd = self._maker_cmd(kind, i, count)
                 self.makers.append({
                     "name": f"{kind}-{i}", "cmd": cmd,
-                    "proc": subprocess.Popen(cmd, env=self.env),
+                    "proc": subprocess.Popen(cmd, env=self.maker_env),
                     "restarts": 0})
         if self.makers:
             print(f"fleet makers: {len(self.makers)} workers", flush=True)
@@ -178,7 +239,7 @@ class Fleet:
             self.maker_restarts += 1
             print(f"fleet: maker {m['name']} crashed (exit {rc}), "
                   f"restarting (x{m['restarts']})", flush=True)
-            m["proc"] = subprocess.Popen(m["cmd"], env=self.env)
+            m["proc"] = subprocess.Popen(m["cmd"], env=self.maker_env)
         for p, (proc, port) in enumerate(self.members):
             if proc.poll() is not None and p not in self._dead_members:
                 self._dead_members.add(p)

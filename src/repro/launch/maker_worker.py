@@ -26,6 +26,7 @@ quiet one).
 from __future__ import annotations
 
 import argparse
+import os
 import signal
 import sys
 import threading
@@ -39,6 +40,7 @@ from repro.configs import ARCH_IDS, get_config
 from repro.core import (MakerRuntime, connect_kb, format_maker_stats,
                         make_embed_fn)
 from repro.data import SyntheticGraphCorpus
+from repro.env import enable_compile_cache
 from repro.models import build_model
 from repro.sharding.partition import DistContext
 
@@ -96,6 +98,12 @@ def main(argv=None) -> int:
     ap.add_argument("--sock-buf", type=int, default=0,
                     help="SO_SNDBUF/SO_RCVBUF bytes (0 = OS default)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
+    # a worker is a client of the bank: launchers pin it to the CPU so the
+    # chips stay with the serving processes
+    print(f"maker-worker: client-only process, jax platform "
+          f"{jax.default_backend()} (JAX_PLATFORMS="
+          f"{os.environ.get('JAX_PLATFORMS') or 'unset'})", flush=True)
 
     kinds = [k.strip() for k in args.makers.split(",") if k.strip()]
     client = connect_kb(

@@ -56,7 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
-from repro.env import add_device_args, apply_device_args
+from repro.env import add_device_args, apply_device_args, enable_compile_cache
 from repro.models import build_model
 from repro.sharding.partition import DistContext
 
@@ -168,8 +168,10 @@ def serve_kb_partitioned(args) -> None:
               f"{int(sm.get('dispatches', 0))} dispatches")
 
 
-def serve_kb(args) -> None:
-    """Concurrent-client KB serving demo on the coalescing server."""
+def serve_kb(args) -> dict:
+    """Concurrent-client KB serving demo on the coalescing server. Returns
+    the run's counters; raises if a client request or the index refresher
+    failed."""
     from repro.core import (KnowledgeBankServer, MakerRuntime,
                             format_maker_stats)
     rng = np.random.default_rng(args.seed)
@@ -277,6 +279,7 @@ def serve_kb(args) -> None:
                              min_period_s=args.kb_maker_period)
         runtime.start()
 
+    client_errors = []
     if args.listen:
         # -- wire-serving mode: host the bank for OTHER processes ---------
         from repro.core import KBTransportServer, parse_hostport
@@ -309,11 +312,14 @@ def serve_kb(args) -> None:
         # -- local-driver mode: synthetic concurrent in-process clients ---
         def client(t: int, n_calls: int):
             crng = np.random.default_rng(args.seed + 1 + t)
-            for _ in range(n_calls):
-                ids = crng.integers(0, args.kb_entries, (args.batch,))
-                vals = server.lookup(ids)
-                server.lazy_grad(ids, 0.01 * vals)
-                server.nn_search(vals, k=8)
+            try:
+                for _ in range(n_calls):
+                    ids = crng.integers(0, args.kb_entries, (args.batch,))
+                    vals = server.lookup(ids)
+                    server.lazy_grad(ids, 0.01 * vals)
+                    server.nn_search(vals, k=8)
+            except Exception as e:      # reported after the summary
+                client_errors.append(e)
 
         threads = [threading.Thread(target=client, args=(t, args.gen))
                    for t in range(args.clients)]
@@ -327,6 +333,7 @@ def serve_kb(args) -> None:
         summary = (f"clients={args.clients}: {calls / dt:.0f} req/s "
                    f"({dt / calls * 1e6:.0f} us/req), ")
     stats = dict(server.engine.search_stats)
+    refresh_error = refresher.last_error if refresher else None
     rebuilds = refresher.rebuilds if refresher else 0
     shard_rebuilds = refresher.shard_rebuilds if refresher else 0
     maker_stats = {}
@@ -340,7 +347,7 @@ def serve_kb(args) -> None:
           f"coalescing x{server.coalescing_factor:.1f}, "
           f"{server.metrics['dispatches']} device dispatches for "
           f"{server.metrics['requests']} requests, "
-          f"nn ivf/exact={stats['ivf']}/{stats['exact']}, "
+          f"search_stats={stats}, "
           f"index rebuilds={rebuilds} ({shard_rebuilds} shard builds)",
           flush=True)
     sst = server.engine.storage_stats()
@@ -368,6 +375,17 @@ def serve_kb(args) -> None:
               f"mean_occ={st['mean_occupancy']:.1f} "
               f"max_occ={st['max_occupancy']} skew=x{st['skew']:.2f} "
               f"headroom={st['headroom']}")
+    if client_errors:
+        raise RuntimeError(
+            f"{len(client_errors)} serving client(s) failed") \
+            from client_errors[0]
+    if refresh_error is not None:
+        raise RuntimeError("IVF index refresher failed") from refresh_error
+    return {"search_stats": stats,
+            "requests": server.metrics["requests"],
+            "dispatches": server.metrics["dispatches"],
+            "index_rebuilds": rebuilds,
+            "interpret": getattr(server.engine.backend, "interpret", None)}
 
 
 def main(argv=None):
@@ -491,6 +509,7 @@ def main(argv=None):
                          "(0 = OS default)")
     add_device_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     apply_device_args(args)
 
     if args.kb:
@@ -521,9 +540,8 @@ def main(argv=None):
                 ap.error("--kb-partitions supports the plain serving "
                          "drive (no --kb-makers/--kb-search ivf yet)")
             serve_kb_partitioned(args)
-        else:
-            serve_kb(args)
-        return
+            return None
+        return serve_kb(args)
 
     cfg = get_config(args.arch).reduced()
     model = build_model(cfg)

@@ -3,8 +3,10 @@
   PYTHONPATH=src python -m repro.launch.train --arch yi-6b --reduced \
       --steps 100 --batch 8 --seq 64
 
-On this CPU container only --reduced configs are runnable; the full configs
-go through the dry-run (repro.launch.dryrun). The default loop is the
+``--reduced`` (the default) trains the CPU-sized variant of the family
+(``ModelConfig.reduced()``); ``--no-reduced`` keeps the published widths,
+with ``--layers`` cutting depth, which is how a chip runs it. The default
+loop is the
 in-graph CARLS step: KB lookup -> loss(CE + graph reg) -> lazy grad push ->
 AdamW, with periodic checkpointing and a maker refresh pass
 (synchronous-maker mode), all KB traffic through the ``KBOps`` facade.
@@ -39,7 +41,7 @@ import numpy as np
 
 from repro.checkpoint import DiskCheckpointStore
 from repro.configs import ARCH_IDS, get_config
-from repro.env import add_device_args, apply_device_args
+from repro.env import add_device_args, apply_device_args, enable_compile_cache
 from repro.core import (format_maker_stats, kb_create,
                         make_carls_train_step, make_embedding_refresh,
                         run_async_training)
@@ -52,7 +54,10 @@ from repro.sharding.partition import DistContext
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="yi-6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the CPU-sized variant (ModelConfig."
+                         "reduced); --no-reduced keeps published widths")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -90,6 +95,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     add_device_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     apply_device_args(args)
     if args.kb_connect and not args.makers:
         # the sync in-graph loop owns its KBState and never talks to a
@@ -133,24 +139,36 @@ def main(argv=None):
     ckpts = DiskCheckpointStore(args.ckpt_dir) if args.ckpt_dir else None
 
     rng = np.random.default_rng(args.seed + 1)
+    history = []                    # per-step device scalars, read at end
+    maker_refreshes = 0
     t0 = time.perf_counter()
     for step in range(args.steps):
         b = corpus.batch(rng, args.batch)
         jb = {k: jnp.asarray(v) for k, v in b.items()}
         params, opt_state, kb, m = step_fn(params, opt_state, kb, jb)
+        history.append((m["loss"], m["kb_pending"]))
         if (step + 1) % args.maker_every == 0:
             ids = rng.integers(0, args.nodes, args.batch).astype(np.int32)
             toks = corpus.node_tokens(ids)[:, :-1]
             kb = maker_fn(params, kb, jnp.asarray(ids), jnp.asarray(toks))
+            maker_refreshes += 1
         if ckpts and (step + 1) % args.ckpt_every == 0:
             ckpts.save(step + 1, params)
         if step < 3 or (step + 1) % 10 == 0:
             print(f"step {step+1:5d} loss={float(m['loss']):.4f} "
                   f"acc={float(m['acc']):.3f} reg={float(m['graph_reg']):.4f}"
-                  f" gnorm={float(m['grad_norm']):.2f}", flush=True)
+                  f" gnorm={float(m['grad_norm']):.2f}"
+                  f" kb_pending={float(m['kb_pending']):.0f}", flush=True)
+    jax.block_until_ready(kb)
     dt = time.perf_counter() - t0
     print(f"done: {args.steps} steps in {dt:.1f}s "
-          f"({dt/args.steps*1e3:.0f} ms/step)")
+          f"({dt/args.steps*1e3:.0f} ms/step), "
+          f"{maker_refreshes} maker refreshes")
+    return {"losses": [float(l) for l, _ in history],
+            "kb_pending": [float(p) for _, p in history],
+            "maker_refreshes": maker_refreshes,
+            "layers": cfg.num_layers, "d_model": cfg.d_model,
+            "params": n_par}
 
 
 def run_async(model, cfg, args) -> None:

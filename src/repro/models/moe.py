@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size, shard_map
 from repro.configs.base import ModelConfig
 
 CAPACITY_FACTOR = 1.25
@@ -178,13 +177,13 @@ def _local_moe(x, wr, wi, wg, wo, *, cfg: ModelConfig, expert_parallel: bool,
     E = cfg.num_experts
     gates, experts, aux = route(x, wr, K)
     if model_axis is not None and expert_parallel:
-        n_model = axis_size(model_axis)
+        n_model = jax.lax.axis_size(model_axis)
         midx = jax.lax.axis_index(model_axis)
         e_loc = E // n_model
         owner = (experts // e_loc) == midx
         offset = midx * e_loc
     else:
-        n_model = (axis_size(model_axis)
+        n_model = (jax.lax.axis_size(model_axis)
                    if model_axis is not None else 1)
         owner, offset, e_loc = None, 0, E
 
@@ -228,8 +227,8 @@ def _decode_moe_sharded(x, wr, wi, wg, wo, *, cfg: ModelConfig, ep: bool,
         x = jax.lax.all_gather(x, da, axis=0, tiled=True)   # (T, D) tiny
     T = x.shape[0]
     gates, experts, aux = route(x, wr, K)
-    n_model = axis_size(ma)
-    n_data = axis_size(da)
+    n_model = jax.lax.axis_size(ma)
+    n_data = jax.lax.axis_size(da)
     if ep:
         e_loc = E // n_model
         midx = jax.lax.axis_index(ma)
@@ -301,7 +300,7 @@ def moe_apply(x, params, *, cfg: ModelConfig, dist, decode: bool = False):
                                          cfg=cfg, ep=ep, dist=dist, dp=dp)
             return y.reshape(xl.shape), jnp.reshape(aux, (1,))
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body_d, mesh=dist.mesh,
             in_specs=(P(dp, None, None), P(None, None), wspec, wspec,
                       wo_spec),
@@ -319,7 +318,7 @@ def moe_apply(x, params, *, cfg: ModelConfig, dist, decode: bool = False):
                             expert_parallel=ep, model_axis=ma, decode=False)
         return y.reshape(xl.shape), jnp.reshape(aux, (1,))
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=dist.mesh,
         in_specs=(P(dp, None, None), P(None, None), wspec, wspec, wo_spec),
         out_specs=(P(dp, None, None), P(dp)),

@@ -57,8 +57,7 @@ def test_async_without_makers_has_stale_bank():
 
 
 def _count_flops(f, *args):
-    from repro.compat import cost_analysis
-    return cost_analysis(jax.jit(f).lower(*args).compile())["flops"]
+    return jax.jit(f).lower(*args).compile().cost_analysis()["flops"]
 
 
 def test_carls_step_flops_flat_in_neighbors_baseline_linear():

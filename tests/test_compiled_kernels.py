@@ -2,11 +2,13 @@
 on a real accelerator, checked against the same jnp oracles the interpret
 lane uses.
 
-On CPU-only runners (the default CI container) the whole module skips with
-an explicit "skipped: no accelerator" marker — run with ``pytest -rs`` so
-the skip is visible rather than silent. On a GPU/TPU runner the tri-state
-auto mode resolves to compiled and these tests execute for real; they can
-also be forced from the CLI lane with ``REPRO_INTERPRET=false``.
+On CPU-only runners (the default CI container) every test skips with an
+explicit "skipped: no accelerator" marker — run with ``pytest -rs`` so the
+skip is visible rather than silent. On a GPU/TPU runner the tri-state auto
+mode resolves to compiled and these tests execute for real; they can also
+be forced from the CLI lane with ``REPRO_INTERPRET=false``. The backend is
+asked inside a fixture, when a test starts, never while the file is
+imported.
 """
 import jax
 import jax.numpy as jnp
@@ -16,11 +18,13 @@ import pytest
 from repro.env import has_accelerator
 from repro.kernels import ops, ref
 
-pytestmark = pytest.mark.skipif(
-    not has_accelerator(),
-    reason="skipped: no accelerator (jax backend is "
-           f"'{jax.default_backend()}') — the compiled interpret=False "
-           "lane needs a gpu/tpu runner")
+
+@pytest.fixture(autouse=True)
+def accelerator():
+    if not has_accelerator():
+        pytest.skip("skipped: no accelerator (jax backend is "
+                    f"'{jax.default_backend()}') — the compiled "
+                    "interpret=False lane needs a gpu/tpu runner")
 
 
 def test_nn_search_topk_compiled():

@@ -632,6 +632,26 @@ def test_connect_kb_third_leg_joins_spare_pool_over_wire():
         _close(servers)
 
 
+def test_chip_plan_one_serving_process_per_chip():
+    """The fleet launcher's chip layout: each serving process owns one
+    chip, a layout with more servers than chips fails before booting, and
+    client-only processes (maker workers) are pinned to the CPU."""
+    from repro.launch.fleet import ChipPlan
+    plan = ChipPlan(2)
+    envs = [plan.serving_env({}) for _ in range(2)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1"]
+    assert len({e["TPU_PROCESS_PORT"] for e in envs}) == 2
+    assert all("JAX_PLATFORMS" not in e for e in envs)
+    with pytest.raises(RuntimeError, match="chip of its own"):
+        plan.serving_env({})
+    with pytest.raises(RuntimeError, match="chip of its own"):
+        ChipPlan(2).require(3)
+    ChipPlan(0).require(3)                  # no chips: all on the CPU
+    assert ChipPlan(0).serving_env({})["JAX_PLATFORMS"] == "cpu"
+    assert ChipPlan.client_env({"JAX_PLATFORMS": "tpu"})[
+        "JAX_PLATFORMS"] == "cpu"
+
+
 # ---------------------------------------------------------------------------
 # separate-process end-to-end: SIGKILL a real fleet member
 # ---------------------------------------------------------------------------

@@ -43,6 +43,11 @@ Boots the real deployment shapes with zero test scaffolding:
 4. SIGTERMs the survivor and the promoted standby and asserts each served
    wire traffic.
 
+Chips: each ``serve`` process gets a chip of its own on a TPU host and a
+phase needing more servers than the host has chips fails at boot
+(``repro.launch.fleet.ChipPlan``); workers and clients run on the CPU.
+``JAX_PLATFORMS=cpu`` runs every process on the CPU.
+
 Usage:
   python tools/smoke_multiproc.py [--phase single|router|mixed|failover|all]
 (exit 0 = pass)
@@ -59,6 +64,9 @@ import sys
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(ROOT, "src"))
+from repro.launch.fleet import ChipPlan, host_chips  # noqa: E402  (no jax)
+
 STARTUP_TIMEOUT_S = 300         # cold jax import + jit warmup on CI
 
 # runs inside a client subprocess (needs the repro jax stack, which the
@@ -142,14 +150,14 @@ print(f"mixed-client OK p99={p99:.2f}ms errors=0 "
 
 
 def _env():
+    """Client-only children (workers, client scripts): pinned to the CPU."""
     env = dict(os.environ)
     env["PYTHONPATH"] = (os.path.join(ROOT, "src")
                          + os.pathsep + env.get("PYTHONPATH", ""))
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    return env
+    return ChipPlan.client_env(env)
 
 
-def _boot_server(extra_args):
+def _boot_server(chips, extra_args):
     """Start a serve.py bank process and return (proc, port) once its
     "listening on" line appears — select-with-deadline, NOT a bare
     readline: a server that wedges before printing anything must fail at
@@ -158,7 +166,7 @@ def _boot_server(extra_args):
         [sys.executable, "-m", "repro.launch.serve", "--kb",
          "--kb-entries", "256", "--kb-dim", "32",
          "--listen", "127.0.0.1:0", "--serve-seconds", "600", *extra_args],
-        env=_env(), cwd=ROOT, stdout=subprocess.PIPE,
+        env=chips.serving_env(_env()), cwd=ROOT, stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True)
     lines = []
     deadline = time.time() + STARTUP_TIMEOUT_S
@@ -210,8 +218,16 @@ def _run_worker(connect_spec):
         raise RuntimeError("worker reported no rows_written")
 
 
+def _chips(n_servers):
+    """A fresh chip plan for one phase, refused at boot if the phase's
+    servers cannot each own a chip."""
+    plan = ChipPlan(host_chips())
+    plan.require(n_servers)
+    return plan
+
+
 def phase_single() -> None:
-    serve, port = _boot_server([])
+    serve, port = _boot_server(_chips(1), [])
     try:
         _run_worker(f"127.0.0.1:{port}")
         _stop_server(serve, "serve")
@@ -223,9 +239,10 @@ def phase_single() -> None:
 
 def phase_router() -> None:
     members = []
+    chips = _chips(2)
     try:
         for i in range(2):
-            members.append(_boot_server(["--kb-join", f"{i}/2"]))
+            members.append(_boot_server(chips, ["--kb-join", f"{i}/2"]))
         spec = ",".join(f"127.0.0.1:{port}" for _, port in members)
 
         client = subprocess.run(
@@ -247,7 +264,7 @@ def phase_router() -> None:
 
 
 def phase_mixed() -> None:
-    serve, port = _boot_server([])
+    serve, port = _boot_server(_chips(1), [])
     try:
         client = subprocess.run(
             [sys.executable, "-c", _MIXED_CLIENT,
@@ -267,13 +284,14 @@ def phase_mixed() -> None:
 def phase_failover() -> None:
     procs = []
     worker = None
+    chips = _chips(3)
     try:
-        p0 = _boot_server(["--kb-join", "0/2"])
+        p0 = _boot_server(chips, ["--kb-join", "0/2"])
         procs.append(p0)
-        p1 = _boot_server(["--kb-join", "1/2"])
+        p1 = _boot_server(chips, ["--kb-join", "1/2"])
         procs.append(p1)
-        s0 = _boot_server(["--kb-join", "0/2",
-                           "--replica-of", f"127.0.0.1:{p0[1]}"])
+        s0 = _boot_server(chips, ["--kb-join", "0/2",
+                                  "--replica-of", f"127.0.0.1:{p0[1]}"])
         procs.append(s0)
         spec = (f"127.0.0.1:{p0[1]}|127.0.0.1:{s0[1]},"
                 f"127.0.0.1:{p1[1]}")
