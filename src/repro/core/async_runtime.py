@@ -93,7 +93,8 @@ class _Request:
     src_step) so staleness accounting happens in execution order."""
 
     __slots__ = ("op", "ids", "payload", "k", "mode", "excl", "shape",
-                 "meta", "event", "result", "error", "_callbacks")
+                 "meta", "event", "result", "error", "_callbacks",
+                 "t_submit")
 
     def __init__(self, op, ids=None, payload=None, k=None, mode=None,
                  excl=None, shape=None, meta=0):
@@ -103,6 +104,7 @@ class _Request:
         self.result = None
         self.error = None
         self._callbacks: list = []
+        self.t_submit = 0.0         # host clock at submission
 
     def wait(self):
         self.event.wait()
@@ -241,10 +243,16 @@ class KnowledgeBankServer:
                         "rows_served": 0, "stale_rows_served": 0,
                         "staleness_sum": 0.0,
                         "requests": 0, "dispatches": 0, "max_run": 0,
-                        "reorders": 0, "cache_hits": 0, "cache_misses": 0}
+                        "reorders": 0, "cache_hits": 0, "cache_misses": 0,
+                        # host seconds: requests queued (submit to pop),
+                        # the dispatcher busy (pop to the batch's last
+                        # reply), and inside it the engine calls
+                        "queue_wait_s": 0.0, "dispatcher_busy_s": 0.0,
+                        "engine_call_s": 0.0}
         self._mlock = threading.Lock()      # metrics + row_src_step
         self._elock = threading.Lock()      # engine state (direct path)
         self._queue: deque = deque()
+        self._runs = 0                      # runs executed; tags kb.run
         self._cond = threading.Condition()
         self._closed = False
         self._dispatcher = None
@@ -359,6 +367,8 @@ class KnowledgeBankServer:
         # them across partitions like any other counter
         m["tier_faults"] = storage["tier_faults"]
         m["tier_spills"] = storage["tier_spills"]
+        m["engine_op_s"] = self.engine.op_s
+        m["engine_wait_s"] = self.engine.wait_s
         return {
             "metrics": m,
             "mean_staleness": float(self.mean_staleness),
@@ -480,6 +490,7 @@ class KnowledgeBankServer:
                                             shape=shape, meta=meta))
 
     def _submit_nowait(self, req: _Request) -> _Request:
+        req.t_submit = time.perf_counter()
         if self.coalesce:
             with self._cond:
                 if self._closed:
@@ -501,7 +512,11 @@ class KnowledgeBankServer:
             with self._mlock:
                 self.metrics["requests"] += 1
         with self._elock:
+            t = time.perf_counter()
             self._execute_run([req])
+            busy = time.perf_counter() - t
+        with self._mlock:
+            self.metrics["dispatcher_busy_s"] += busy
         return req
 
     def _submit(self, req: _Request):
@@ -509,20 +524,30 @@ class KnowledgeBankServer:
 
     def _dispatch_loop(self):
         while True:
-            with self._cond:
-                while not self._queue and not self._closed:
-                    self._cond.wait()
-                if self._closed and not self._queue:
-                    return
-            if self.coalesce_window_s:
-                time.sleep(self.coalesce_window_s)   # let the queue fill
-            with self._cond:
-                batch = [self._queue.popleft()
-                         for _ in range(min(len(self._queue),
-                                            self.max_coalesce))]
-            for run in self._form_runs(batch):
+            with jax.profiler.TraceAnnotation("kb.dispatch.wait"):
+                with self._cond:
+                    while not self._queue and not self._closed:
+                        self._cond.wait()
+                    if self._closed and not self._queue:
+                        return
+                if self.coalesce_window_s:
+                    time.sleep(self.coalesce_window_s)  # let the queue fill
+            with jax.profiler.TraceAnnotation("kb.dispatch.form"):
+                with self._cond:
+                    t_pop = time.perf_counter()
+                    batch = [self._queue.popleft()
+                             for _ in range(min(len(self._queue),
+                                                self.max_coalesce))]
+                runs = self._form_runs(batch)
+            for run in runs:
                 with self._elock:
                     self._execute_run(run)
+            busy = time.perf_counter() - t_pop
+            waited = sum(t_pop - r.t_submit for r in batch
+                         if r.op != "barrier")
+            with self._mlock:
+                self.metrics["queue_wait_s"] += waited
+                self.metrics["dispatcher_busy_s"] += busy
 
     def _form_runs(self, batch: List[_Request]) -> List[List[_Request]]:
         """Group a popped batch into runs, each one batched device dispatch.
@@ -568,74 +593,119 @@ class KnowledgeBankServer:
         return runs
 
     def _execute_run(self, run: List[_Request]):
-        op = run[0].op
+        """One batched engine call for ``run``, inside a ``kb.run`` span
+        whose children split the host work around the call: ``kb.run.args``
+        (concatenating the requests) and ``kb.run.reply`` (slicing results,
+        accounting, waking the callers)."""
+        op, seq = run[0].op, self._runs
+        self._runs += 1
+        n_ids = sum(r.ids.size for r in run if r.ids is not None)
+        with jax.profiler.TraceAnnotation("kb.run", op=op, run=seq,
+                                          n_req=len(run), n_ids=n_ids):
+            args = out = error = None
+            try:
+                with jax.profiler.TraceAnnotation("kb.run.args"):
+                    fn, args, kwargs = self._run_call(run)
+                if fn is not None:
+                    out = self._call_engine(seq, fn, args, kwargs)
+            except Exception as e:      # deliver, don't kill the dispatcher
+                error = e
+            finally:
+                with jax.profiler.TraceAnnotation("kb.run.reply"):
+                    try:
+                        if error is None:
+                            self._reply(run, args, out)
+                    except Exception as e:
+                        error = e
+                    finally:
+                        for r in run:
+                            if error is not None:
+                                r.error = error
+                            r.event.set()
+                            r._fire_callbacks()
+
+    def _run_call(self, run: List[_Request]):
+        """(engine method, args, kwargs) of a run's one engine call; the
+        method is None for a barrier."""
+        op, eng = run[0].op, self.engine
+        if op == "lookup":
+            fn = self._cached_lookup if self.cache_rows > 0 else eng.lookup
+            return fn, (np.concatenate([r.ids for r in run]),), {}
+        if op in ("update", "lazy_grad"):
+            return getattr(eng, op), (np.concatenate([r.ids for r in run]),
+                                      np.concatenate([r.payload
+                                                      for r in run])), {}
+        if op == "flush":
+            return eng.flush, (), {}
+        if op == "nn":
+            excl = (None if run[0].excl is None
+                    else np.concatenate([r.excl for r in run]))
+            return eng.nn_search, (np.concatenate([r.payload for r in run]),
+                                   run[0].k), {"mode": run[0].mode,
+                                               "exclude_ids": excl}
+        return None, (), {}
+
+    def _call_engine(self, seq: int, fn, args, kwargs):
+        """Call the engine for run ``seq``; its host seconds go to
+        ``engine_call_s`` and its device calls to ``dispatches``."""
+        eng = self.engine
+        before = eng.dispatches
+        eng.current_run = seq
+        t = time.perf_counter()
         try:
-            before = self.engine.dispatches
-            if op == "lookup":
-                ids = np.concatenate([r.ids for r in run])
-                vals = (self._cached_lookup(ids) if self.cache_rows > 0
-                        else self.engine.lookup(ids))
-                off = 0
-                for r in run:
-                    n = r.ids.size
-                    r.result = vals[off:off + n].reshape(*r.shape, -1)
-                    off += n
-                # staleness is accounted HERE, in execution order, so a
-                # concurrent maker update landing after this run cannot
-                # retag rows this lookup served from the older checkpoint
-                with self._mlock:
-                    for r in run:
-                        src = self._row_src_step[r.ids]
-                        known = src >= 0
-                        self.metrics["lookups"] += 1
-                        self.metrics["rows_served"] += r.ids.size
-                        self.metrics["stale_rows_served"] += int(
-                            (known & (src < r.meta)).sum())
-                        self.metrics["staleness_sum"] += float(
-                            np.maximum(r.meta - src[known], 0).sum())
-            elif op == "update":
-                w_ids = np.concatenate([r.ids for r in run])
-                self.engine.update(w_ids,
-                                   np.concatenate([r.payload for r in run]))
-                self._invalidate_cache(w_ids)
-                with self._mlock:
-                    for r in run:
-                        self._row_src_step[r.ids] = r.meta
-                        self.metrics["updates"] += 1
-            elif op == "lazy_grad":
-                w_ids = np.concatenate([r.ids for r in run])
-                self.engine.lazy_grad(
-                    w_ids, np.concatenate([r.payload for r in run]))
-                self._invalidate_cache(w_ids)
-                with self._mlock:
-                    self.metrics["lazy_grads"] += len(run)
-            elif op == "flush":
-                self.engine.flush()
-                self._row_cache.clear()
-            elif op == "nn":
-                sizes = [r.payload.shape[0] for r in run]
-                excl = (None if run[0].excl is None
-                        else np.concatenate([r.excl for r in run]))
-                scores, ids = self.engine.nn_search(
-                    np.concatenate([r.payload for r in run]), run[0].k,
-                    mode=run[0].mode, exclude_ids=excl)
-                off = 0
-                for r, n in zip(run, sizes):
-                    r.result = (scores[off:off + n], ids[off:off + n])
-                    off += n
-            elif op == "barrier":
-                pass
-            with self._mlock:
-                self.metrics["dispatches"] += self.engine.dispatches - before
-                self.metrics["max_run"] = max(self.metrics["max_run"],
-                                              len(run))
-        except Exception as e:          # deliver, don't kill the dispatcher
-            for r in run:
-                r.error = e
+            return fn(*args, **kwargs)
         finally:
+            dt = time.perf_counter() - t
+            eng.current_run = -1
+            with self._mlock:
+                self.metrics["engine_call_s"] += dt
+                self.metrics["dispatches"] += eng.dispatches - before
+
+    def _reply(self, run: List[_Request], args: tuple, out) -> None:
+        """Hand each request its slice of the run's result ``out`` and
+        account the run (staleness, op counters, the write-invalidated
+        cache); ``args`` are the engine call's arguments."""
+        op = run[0].op
+        if op == "lookup":
+            off = 0
             for r in run:
-                r.event.set()
-                r._fire_callbacks()
+                n = r.ids.size
+                r.result = out[off:off + n].reshape(*r.shape, -1)
+                off += n
+            # staleness is accounted HERE, in execution order, so a
+            # concurrent maker update landing after this run cannot
+            # retag rows this lookup served from the older checkpoint
+            with self._mlock:
+                for r in run:
+                    src = self._row_src_step[r.ids]
+                    known = src >= 0
+                    self.metrics["lookups"] += 1
+                    self.metrics["rows_served"] += r.ids.size
+                    self.metrics["stale_rows_served"] += int(
+                        (known & (src < r.meta)).sum())
+                    self.metrics["staleness_sum"] += float(
+                        np.maximum(r.meta - src[known], 0).sum())
+        elif op == "update":
+            self._invalidate_cache(args[0])
+            with self._mlock:
+                for r in run:
+                    self._row_src_step[r.ids] = r.meta
+                    self.metrics["updates"] += 1
+        elif op == "lazy_grad":
+            self._invalidate_cache(args[0])
+            with self._mlock:
+                self.metrics["lazy_grads"] += len(run)
+        elif op == "flush":
+            self._row_cache.clear()
+        elif op == "nn":
+            scores, ids = out
+            off = 0
+            for r in run:
+                n = r.payload.shape[0]
+                r.result = (scores[off:off + n], ids[off:off + n])
+                off += n
+        with self._mlock:
+            self.metrics["max_run"] = max(self.metrics["max_run"], len(run))
 
     def _cached_lookup(self, ids: np.ndarray) -> np.ndarray:
         """Hot-id LRU read path (see __init__): serve repeats from host

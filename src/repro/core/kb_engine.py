@@ -62,6 +62,8 @@ index.
 """
 from __future__ import annotations
 
+import functools
+import time
 from collections import OrderedDict
 from typing import Callable, NamedTuple, Optional, Protocol, Tuple
 
@@ -355,6 +357,23 @@ def make_kb_ops(dist: Optional[DistContext] = None, *,
     )
 
 
+def _engine_op(fn):
+    """Run a ``KBEngine`` op method inside a ``kb.engine.<op>`` host span
+    tagged with the server's run number, and add its host seconds to
+    ``op_s``. The span is recorded only while the profiler runs."""
+    name = f"kb.engine.{fn.__name__}"
+
+    @functools.wraps(fn)
+    def op(self, *args, **kw):
+        t = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, run=self.current_run):
+                return fn(self, *args, **kw)
+        finally:
+            self.op_s += time.perf_counter() - t
+    return op
+
+
 def _bucket(n: int, minimum: int = 8) -> int:
     """Next power-of-two jit bucket (>= minimum)."""
     return max(minimum, 1 << max(n - 1, 0).bit_length())
@@ -483,6 +502,13 @@ class KBEngine:
         # re-ranking in int8 mode; invalidated per-id by lazy_grad
         self._masters: "OrderedDict[int, np.ndarray]" = OrderedDict()
         self.dispatches = 0         # device calls issued (bench metric)
+        # host seconds inside the op methods, and the part of them spent
+        # blocked on a result's device-to-host copy (``kb.engine.wait``)
+        self.op_s = 0.0
+        self.wait_s = 0.0
+        # the server's run number while it calls an op (-1: a direct
+        # call); tags the ``kb.engine.*`` spans
+        self.current_run = -1
 
         bk = self.backend
         if self._quantized:
@@ -491,7 +517,7 @@ class KBEngine:
                     kb_fused_lookup_q_pallas)
                 n_block, interp = bk.n_block, bk.interpret
 
-                def _lookup_q(st, qs, qo, ids):
+                def kb_lookup_q(st, qs, qo, ids):
                     vals, tbl, s, o, gsum, gcnt, gsq = (
                         kb_fused_lookup_q_pallas(
                             st.table, qs, qo, st.grad_sum, st.grad_cnt,
@@ -506,38 +532,61 @@ class KBEngine:
                                      grad_sqnorm=gsq)
                     return vals, st, s, o
             else:
-                def _lookup_q(st, qs, qo, ids):
+                def kb_lookup_q(st, qs, qo, ids):
                     return kbm.kb_lookup_q(st, qs, qo, ids,
                                            lazy_lr=lazy_lr, zmax=zmax)
-            self._lookup_fn = jax.jit(_lookup_q)
-            self._update_fn = jax.jit(
-                lambda st, qs, qo, ids, v: kbm.kb_update_q(st, qs, qo,
-                                                           ids, v))
-            self._flush_fn = jax.jit(
-                lambda st, qs, qo: kbm.kb_flush_q(st, qs, qo,
-                                                  lazy_lr=lazy_lr,
-                                                  zmax=zmax))
+
+            def kb_update(st, qs, qo, ids, v):
+                return kbm.kb_update_q(st, qs, qo, ids, v)
+
+            def kb_flush(st, qs, qo):
+                return kbm.kb_flush_q(st, qs, qo, lazy_lr=lazy_lr, zmax=zmax)
+
+            self._lookup_fn = jax.jit(kb_lookup_q)
         else:
-            self._lookup_fn = jax.jit(lambda st, ids: bk.lookup(
-                st, ids, lazy_lr=lazy_lr, zmax=zmax,
-                apply_pending=lazy_update))
-            self._update_fn = jax.jit(
-                lambda st, ids, v: bk.update(st, ids, v))
-            self._flush_fn = jax.jit(lambda st: bk.flush(
-                st, lazy_lr=lazy_lr, zmax=zmax))
+            def kb_lookup(st, ids):
+                return bk.lookup(st, ids, lazy_lr=lazy_lr, zmax=zmax,
+                                 apply_pending=lazy_update)
+
+            def kb_update(st, ids, v):
+                return bk.update(st, ids, v)
+
+            def kb_flush(st):
+                return bk.flush(st, lazy_lr=lazy_lr, zmax=zmax)
+
+            self._lookup_fn = jax.jit(kb_lookup)
+        # each jitted op is a named function, not a lambda: XLA names the
+        # device ops in a profile after it
+        self._update_fn = jax.jit(kb_update)
+        self._flush_fn = jax.jit(kb_flush)
+
         # lazy_grad only touches the fp32 gradient caches — never the table
         # — so the fp32 op serves both storage modes unchanged
-        self._lazy_fn = jax.jit(lambda st, ids, g, m: bk.lazy_grad(
-            st, ids, g, zmax=entry_zmax, mask=m))
+        def kb_lazy_grad(st, ids, g, m):
+            return bk.lazy_grad(st, ids, g, zmax=entry_zmax, mask=m)
+
         # ablation baseline: immediate SGD scatter, no cache (lazy_update
         # off). mask keeps padded entries inert (g * 0).
-        self._immediate_fn = jax.jit(lambda st, ids, g, m: st._replace(
-            table=st.table.at[ids].add(
-                (-lazy_lr * g * m[:, None]).astype(st.table.dtype))))
+        def kb_immediate_grad(st, ids, g, m):
+            return st._replace(table=st.table.at[ids].add(
+                (-lazy_lr * g * m[:, None]).astype(st.table.dtype)))
+
+        self._lazy_fn = jax.jit(kb_lazy_grad)
+        self._immediate_fn = jax.jit(kb_immediate_grad)
         self._nn_fns = {}
+
+    def _fetch(self, *arrays) -> tuple:
+        """Copy an op's results to the host: its one wait on the device,
+        inside a ``kb.engine.wait`` span and added to ``wait_s``."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("kb.engine.wait"):
+            out = tuple(np.asarray(a) for a in arrays)
+        self.wait_s += time.perf_counter() - t
+        return out
 
     # -- embedding ops -----------------------------------------------------
 
+    @_engine_op
     def lookup(self, ids) -> np.ndarray:
         """Fetch rows (applying pending lazy updates first); any id shape.
         Deterministic under duplicate ids and pow2 padding (pads with a
@@ -558,8 +607,10 @@ class KBEngine:
             vals, self.state = self._lookup_fn(self.state,
                                                jnp.asarray(padded))
         self.dispatches += 1
-        return np.asarray(vals[:flat.size]).reshape(*ids.shape, -1)
+        (vals,) = self._fetch(vals[:flat.size])
+        return vals.reshape(*ids.shape, -1)
 
+    @_engine_op
     def update(self, ids, values) -> None:
         """Direct write (maker push); duplicate ids resolve last-writer-wins
         (host-side dedupe — device scatter order is unspecified). Each
@@ -599,6 +650,7 @@ class KBEngine:
             self._gen += n
             self._spill_cold()
 
+    @_engine_op
     def lazy_grad(self, ids, grads) -> None:
         """Cache gradients (or apply immediately when lazy_update=False).
         Padded entries carry a 0 mask and are inert; cache adds commute,
@@ -769,6 +821,7 @@ class KBEngine:
                 np.clip(ids // n_local, 0, self.ann_shards - 1),
                 minlength=self.ann_shards).astype(np.int64)
 
+    @_engine_op
     def flush(self) -> None:
         """Expiration path: apply every pending cached gradient now.
         (Flushed rows were already counted toward ``total_write_rows`` when
@@ -782,6 +835,7 @@ class KBEngine:
             self.state = self._flush_fn(self.state)
         self.dispatches += 1
 
+    @_engine_op
     def nn_search(self, queries, k: int, *, mode: Optional[str] = None,
                   exclude_ids: Optional[np.ndarray] = None
                   ) -> Tuple[np.ndarray, np.ndarray]:
@@ -799,18 +853,23 @@ class KBEngine:
         E) requests into one batched call and slice the results without
         changing any caller's answer."""
         queries = np.asarray(queries, np.float32)
+        if exclude_ids is None:
+            return self._nn_topk(queries, k, mode)
+        excl = np.asarray(exclude_ids, np.int32).reshape(queries.shape[0],
+                                                           -1)
+        scores, ids = self._nn_topk(queries, k + excl.shape[1], mode)
+        banned = ((ids[:, :, None] == excl[:, None, :])
+                  & (excl[:, None, :] >= 0)).any(-1)
+        scores = np.where(banned, -np.inf, scores)
+        ids = np.where(banned, -1, ids)
+        order = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+        return (np.take_along_axis(scores, order, 1),
+                np.take_along_axis(ids, order, 1))
+
+    def _nn_topk(self, queries: np.ndarray, k: int, mode: Optional[str]
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+        """``nn_search`` without exclusions: one padded device call."""
         B = queries.shape[0]
-        if exclude_ids is not None:
-            excl = np.asarray(exclude_ids, np.int32).reshape(B, -1)
-            scores, ids = self.nn_search(queries, k + excl.shape[1],
-                                         mode=mode)
-            banned = ((ids[:, :, None] == excl[:, None, :])
-                      & (excl[:, None, :] >= 0)).any(-1)
-            scores = np.where(banned, -np.inf, scores)
-            ids = np.where(banned, -1, ids)
-            order = np.argsort(-scores, axis=1, kind="stable")[:, :k]
-            return (np.take_along_axis(scores, order, 1),
-                    np.take_along_axis(ids, order, 1))
         pad = _bucket(B) - B
         q = np.concatenate([queries, np.zeros((pad, queries.shape[1]),
                                               np.float32)])
@@ -841,12 +900,12 @@ class KBEngine:
                     # decomposition — no dequantized (N, D) materialized
                     # (the blocked fp32 Pallas kernel has no int8 twin;
                     # int8 serving is expected to run IVF anyway)
-                    self._nn_fns[k] = jax.jit(
-                        lambda st, qs, qo, q: kbm.kb_nn_search_q(
-                            st, qs, qo, q, k))
+                    def kb_nn_exact(st, qs, qo, q):
+                        return kbm.kb_nn_search_q(st, qs, qo, q, k)
                 else:
-                    self._nn_fns[k] = jax.jit(
-                        lambda st, q: bk.nn_search(st, q, k))
+                    def kb_nn_exact(st, q):
+                        return bk.nn_search(st, q, k)
+                self._nn_fns[k] = jax.jit(kb_nn_exact)
             if self._quantized:
                 scores, ids = self._nn_fns[k](self.state, self._qscale,
                                               self._qoffset, jnp.asarray(q))
@@ -854,7 +913,7 @@ class KBEngine:
                 scores, ids = self._nn_fns[k](self.state, jnp.asarray(q))
             self.search_stats["exact"] += 1
         self.dispatches += 1
-        scores, out_ids = np.asarray(scores[:B]), np.asarray(ids[:B])
+        scores, out_ids = self._fetch(scores[:B], ids[:B])
         if self.tiered:
             scores, out_ids = self._tier_translate(scores, out_ids)
         if self._quantized and self._masters:
@@ -910,40 +969,43 @@ class KBEngine:
             if isinstance(self.backend, ShardedBackend):
                 bk = self.backend
                 if self.storage == "int8":
-                    impl = (lambda tbl, c, pc, ps, po, pi, occ, q:
-                            bk.nn_search_ivf_q(tbl, c, pc, ps, po, pi, q,
-                                               k, nprobe))
+                    def kb_nn_ivf(tbl, c, pc, ps, po, pi, occ, q):
+                        return bk.nn_search_ivf_q(tbl, c, pc, ps, po, pi, q,
+                                                  k, nprobe)
                 else:
-                    impl = (lambda tbl, c, pv, pi, occ, q: bk.nn_search_ivf(
-                        tbl, c, pv, pi, q, k, nprobe))
+                    def kb_nn_ivf(tbl, c, pv, pi, occ, q):
+                        return bk.nn_search_ivf(tbl, c, pv, pi, q, k, nprobe)
             elif self._quantized:
                 if isinstance(self.backend, PallasBackend):
                     from repro.kernels.nn_search_ivf import (
                         ivf_search_quantized_pallas)
                     interpret = self.backend.interpret
-                    impl = (lambda tbl, qs, qo, c, pc, ps, po, pi, occ, q:
-                            ivf_search_quantized_pallas(
-                                tbl, qs, qo, c, pc, ps, po, pi, q, k,
-                                nprobe, bucket_occ=occ,
-                                interpret=interpret))
+
+                    def kb_nn_ivf(tbl, qs, qo, c, pc, ps, po, pi, occ, q):
+                        return ivf_search_quantized_pallas(
+                            tbl, qs, qo, c, pc, ps, po, pi, q, k, nprobe,
+                            bucket_occ=occ, interpret=interpret)
                 else:
                     from repro.kernels.nn_search_ivf import (
                         ivf_search_quantized_jnp)
-                    impl = (lambda tbl, qs, qo, c, pc, ps, po, pi, occ, q:
-                            ivf_search_quantized_jnp(
-                                tbl, qs, qo, c, pc, ps, po, pi, q, k,
-                                nprobe))
+
+                    def kb_nn_ivf(tbl, qs, qo, c, pc, ps, po, pi, occ, q):
+                        return ivf_search_quantized_jnp(
+                            tbl, qs, qo, c, pc, ps, po, pi, q, k, nprobe)
             elif isinstance(self.backend, PallasBackend):
                 from repro.kernels.nn_search_ivf import ivf_search_pallas
                 interpret = self.backend.interpret
-                impl = (lambda tbl, c, pv, pi, occ, q: ivf_search_pallas(
-                    tbl, c, pv, pi, q, k, nprobe, bucket_occ=occ,
-                    interpret=interpret))
+
+                def kb_nn_ivf(tbl, c, pv, pi, occ, q):
+                    return ivf_search_pallas(tbl, c, pv, pi, q, k, nprobe,
+                                             bucket_occ=occ,
+                                             interpret=interpret)
             else:
                 from repro.kernels.nn_search_ivf import ivf_search_jnp
-                impl = (lambda tbl, c, pv, pi, occ, q: ivf_search_jnp(
-                    tbl, c, pv, pi, q, k, nprobe))
-            fn = self._ivf_fns[(k, nprobe)] = jax.jit(impl)
+
+                def kb_nn_ivf(tbl, c, pv, pi, occ, q):
+                    return ivf_search_jnp(tbl, c, pv, pi, q, k, nprobe)
+            fn = self._ivf_fns[(k, nprobe)] = jax.jit(kb_nn_ivf)
         occ = idx.bucket_occ
         if self._quantized:
             return fn(self.state.table, self._qscale, self._qoffset,

@@ -148,6 +148,7 @@ def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="kb_fused_lookup",
     )(idp[None, :], pad(table), pad(grad_sum), pad(cnt2),
       pad(sq2))
     new_tbl, gsum, gcnt, gsq, vals = out
@@ -261,6 +262,7 @@ def kb_fused_lookup_q_pallas(table, qscale, qoffset, grad_sum, grad_cnt,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="kb_fused_lookup_q",
     )(idp[None, :], pad(table), sclp, pad(qoffset[:, None]), pad(grad_sum),
       pad(grad_cnt[:, None]), pad(grad_sqnorm[:, None]))
     new_tbl, scl, off, gsum, gcnt, gsq, vals = out

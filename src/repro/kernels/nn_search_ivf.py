@@ -289,6 +289,7 @@ def _stage2_call(sel, nvalid, queries, vecs, ids, k: int, *, lb: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="ivf_stage2_q" if quantized else "ivf_stage2",
     )(sel, nvalid, queries, vecs, *per_slot)
     return (jnp.transpose(out_s[:, :B], (1, 0, 2)),
             jnp.transpose(out_i[:, :B], (1, 0, 2)))

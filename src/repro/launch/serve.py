@@ -61,6 +61,21 @@ from repro.models import build_model
 from repro.sharding.partition import DistContext
 
 
+def format_kb_timing(m: dict) -> str:
+    """Mean queue wait per request, and the dispatcher's and the engine's
+    host time per device dispatch, from a server's (or a fleet's summed)
+    ``stats()["metrics"]``."""
+    req = max(m.get("requests", 0), 1)
+    disp = max(m.get("dispatches", 0), 1)
+    wait = m.get("queue_wait_s", 0.0) / req
+    dispatcher = (m.get("dispatcher_busy_s", 0.0)
+                  - m.get("engine_call_s", 0.0)) / disp
+    engine = (m.get("engine_op_s", 0.0) - m.get("engine_wait_s", 0.0)) / disp
+    return (f"kb host time: queue wait {wait * 1e3:.3f} ms/request, "
+            f"dispatcher {dispatcher * 1e3:.3f} ms/dispatch, "
+            f"engine {engine * 1e3:.3f} ms/dispatch")
+
+
 def serve_kb_partitioned(args) -> None:
     """``--kb-partitions N``: the scale-out topology in one process — N
     partition servers behind a ``KBRouter``, synthetic clients driving the
@@ -152,6 +167,7 @@ def serve_kb_partitioned(args) -> None:
           f"router fast-path "
           f"{stats['router']['single_partition_fastpath']}"
           f"/{stats['router']['fanouts']} fan-outs", flush=True)
+    print(format_kb_timing(m), flush=True)
     sst = stats.get("storage", {})
     if sst:
         print(f"  fleet storage mode={sst['mode']} "
@@ -359,6 +375,7 @@ def serve_kb(args) -> dict:
           f"/{server.metrics['cache_misses']}, "
           f"tier faults/spills={sst['tier_faults']}/{sst['tier_spills']}",
           flush=True)
+    print(format_kb_timing(server.stats()["metrics"]), flush=True)
     for line in format_maker_stats(maker_stats):
         print(line)
     if index is not None and hasattr(index, "shard_stats"):
