@@ -10,13 +10,11 @@ This module makes that contract explicit:
 - ``ShardedBackend``: the mesh-sharded shard_map ops from
                     ``repro.core.sharded_kb`` (owner-masked scatters, psum
                     fan-in) — same math, distributed state.
-- ``PallasBackend``: the TPU serving path. ``lookup`` runs the fused
-                    gather + lazy-apply + cache-clear kernel
-                    (``repro.kernels.kb_fused_lookup``) — one HBM pass
-                    instead of six gather/scatters; ``flush`` runs the
-                    fused ``lazy_apply`` kernel; ``nn_search`` the blocked
-                    MIPS kernel. Writes (update / lazy_grad) are plain
-                    scatters with nothing to fuse and stay on the jnp path.
+- ``PallasBackend``: the TPU serving path. ``flush`` runs the fused
+                    ``lazy_apply`` kernel, ``nn_search`` the blocked MIPS
+                    kernel. ``lookup`` and the writes (update / lazy_grad)
+                    gather and scatter only the requested rows by id (the
+                    jnp path): their work is O(B·D), not O(N·D).
 
 Backends are interchangeable bit-for-bit (tests/test_kb_engine.py drives
 the same op sequence through all three and compares every state leaf).
@@ -214,43 +212,28 @@ class ShardedBackend:
 
 
 class PallasBackend:
-    """TPU serving path: fused single-pass kernels for the read-side ops.
+    """TPU serving path: Pallas kernels for the ops that scan the bank.
+
+    ``flush`` and ``nn_search`` visit every row, so they run as blocked
+    kernels. ``lookup`` touches only its B requested rows, so it gathers
+    them and their caches by id, applies the clipped pending average and
+    scatters them back with their caches cleared: the dense reference's
+    own XLA gather and scatter. A kernel that streams all N rows to find
+    its B would move N·D bytes a call instead of B·D.
 
     ``interpret=None`` (default) resolves ONCE at construction from the
     process ``KernelConfig`` (repro.env): interpret mode on CPU, compiled
-    on an accelerator backend. ``n_block=None`` defers tile sizing to the
-    per-call VMEM fit (``repro.env.fused_lookup_block``), so serving
-    batches past 4k ids pick a legal smaller tile instead of overflowing
-    VMEM."""
+    on an accelerator backend."""
 
     name = "pallas"
 
-    def __init__(self, *, interpret: Optional[bool] = None,
-                 n_block: Optional[int] = None):
+    def __init__(self, *, interpret: Optional[bool] = None):
         from repro.env import resolve_interpret
         self.interpret = resolve_interpret(interpret)
-        self.n_block = n_block
 
     def lookup(self, state, ids, *, lazy_lr, zmax, apply_pending=True):
-        from repro.kernels.kb_fused_lookup import kb_fused_lookup_pallas
-        from repro.kernels.kb_gather import kb_gather_pallas
-        flat = ids.reshape(-1)
-        if not apply_pending:
-            vals = kb_gather_pallas(state.table, flat,
-                                    interpret=self.interpret)
-            return vals.astype(jnp.float32).reshape(*ids.shape, -1), state
-        vals, tbl, gsum, gcnt, gsq = kb_fused_lookup_pallas(
-            state.table, state.grad_sum, state.grad_cnt, state.grad_sqnorm,
-            flat, lazy_lr=lazy_lr, zmax=zmax, n_block=self.n_block,
-            interpret=self.interpret)
-        # version is (N,) metadata: bump once per touched row, jnp-side
-        touched = jnp.zeros(state.version.shape, bool).at[flat].set(
-            True, mode="drop")
-        version = state.version + (touched &
-                                   (state.grad_cnt > 0)).astype(jnp.int32)
-        state = state._replace(table=tbl, version=version, grad_sum=gsum,
-                               grad_cnt=gcnt, grad_sqnorm=gsq)
-        return vals.reshape(*ids.shape, -1), state
+        return kbm.kb_lookup(state, ids, lazy_lr=lazy_lr, zmax=zmax,
+                             apply_pending=apply_pending)
 
     def update(self, state, ids, values):
         return kbm.kb_update(state, ids, values)
@@ -515,14 +498,14 @@ class KBEngine:
             if isinstance(bk, PallasBackend):
                 from repro.kernels.kb_fused_lookup import (
                     kb_fused_lookup_q_pallas)
-                n_block, interp = bk.n_block, bk.interpret
+                interp = bk.interpret
 
                 def kb_lookup_q(st, qs, qo, ids):
                     vals, tbl, s, o, gsum, gcnt, gsq = (
                         kb_fused_lookup_q_pallas(
                             st.table, qs, qo, st.grad_sum, st.grad_cnt,
                             st.grad_sqnorm, ids, lazy_lr=lazy_lr, zmax=zmax,
-                            n_block=n_block, interpret=interp))
+                            interpret=interp))
                     touched = jnp.zeros(st.version.shape, bool).at[ids].set(
                         True, mode="drop")
                     version = st.version + (

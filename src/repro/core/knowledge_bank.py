@@ -29,8 +29,9 @@ see ``repro.core.async_runtime``):
 
 The three engine backends build on this layer: ``DenseBackend`` calls these
 ops directly, ``repro.core.sharded_kb`` re-expresses them as owner-masked
-shard_map ops, and the Pallas backend fuses lookup's gather + lazy-apply +
-cache-clear into a single-pass kernel (``repro.kernels.kb_fused_lookup``).
+shard_map ops, and the Pallas backend calls them for lookups and writes
+and runs blocked kernels for the ops that scan the whole bank
+(``kb_flush``, ``kb_nn_search``).
 """
 from __future__ import annotations
 

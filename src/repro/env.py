@@ -33,8 +33,8 @@ platform at call time", which is what lets the same binary run compiled on
 TPU and interpreted in the CPU CI container with zero flags.
 
 VMEM-aware tile sizing (`fused_lookup_block`, `fit_block_rows`) lives here
-too: the fused-lookup kernel carries a (B, n_block) one-hot and a (B, D)
-accumulator in VMEM, so a serving batch of >4k ids with the old fixed
+too: the int8 fused-lookup kernel carries a (B, n_block) one-hot and a
+(B, D) accumulator in VMEM, so a serving batch of >4k ids with the old fixed
 n_block=512 would blow the ~16 MiB budget on a real core — the helpers
 shrink the bank tile until the working set fits instead of failing (or
 silently spilling) on device.
@@ -254,13 +254,15 @@ def fit_block_rows(dim: int, *, want: Optional[int] = None,
 
 def fused_lookup_block(batch: int, dim: int, *, want: Optional[int] = None,
                        budget: Optional[int] = None) -> int:
-    """Bank-tile rows for the fused-lookup family: those kernels hold an
-    (n_block, B) one-hot, a resident (B, D) fp32 output, and ~10 streamed
-    (n_block, D) tiles in VMEM at once; every row is padded to whole
-    128-lane vregs. For B > 4k ids the old fixed
-    n_block=512 overflows a 16 MiB core — this shrinks the tile until the
-    working set fits (and the batch-shaped scratch alone exceeding the
-    budget raises rather than producing an illegal tile)."""
+    """Bank-tile rows for the int8 fused lookup
+    (``repro.kernels.kb_fused_lookup``): it holds an (n_block, B) one-hot,
+    a resident (B, D) fp32 output, and ~10 streamed (n_block, D) tiles in
+    VMEM at once; every row is padded to whole 128-lane vregs. For B > 4k
+    ids the old fixed n_block=512 overflows a 16 MiB core — this shrinks
+    the tile until the working set fits (and the batch-shaped scratch
+    alone exceeding the budget raises rather than producing an illegal
+    tile). The fp32 lookup has no tile: it gathers its rows by id, so its
+    work follows B, not the bank's N rows."""
     cfg = kernel_config()
     want = cfg.block_ids if want is None else want
     budget = cfg.vmem_limit_bytes if budget is None else budget

@@ -1,25 +1,29 @@
-"""Fused KB lookup kernel: gather + lazy-apply + cache-clear in ONE pass.
+"""Fused int8 KB lookup kernel: dequant + lazy-apply + requant +
+cache-clear in ONE pass over the bank.
 
-The serving hot path of the Knowledge Bank (§3.2) is "apply the cached
-gradient average to the requested rows, then return them". Composed from the
-unfused jnp ops that is six HBM passes over the touched state (gather rows,
-gather caches, scatter new rows, scatter three cleared caches); composed
-from ``kb_gather`` + ``lazy_apply`` it is still two kernels and an extra
-round-trip of the row block. This kernel streams each (bank, grad_sum,
-grad_cnt, grad_sqnorm) tile HBM->VMEM exactly once and, per tile:
+Only the int8-coded bank (``KBEngine(storage="int8")`` on the Pallas
+backend) runs this kernel. The fp32 lookup gathers and scatters its B rows
+by id instead (``repro.core.kb_engine.PallasBackend``): the grid here runs
+over every bank tile, so each call streams all N rows and their caches
+and gathers by a (tile rows, B) one-hot product — N·D bytes and 2·N·B·D
+FLOPs a call where a by-id gather needs B·D. Moving this kernel to a by-id
+gather as well waits for a benchmark cell that runs the int8 bank.
+
+Per bank tile the kernel:
 
 1. builds the (tile rows, B) one-hot membership of the requested ids,
-2. computes the outlier-clipped cached-gradient average (``pending_delta``
-   semantics, same formula as ``repro.core.knowledge_bank``),
-3. writes back the updated table tile and zeroed caches for touched rows,
-4. accumulates ``onehot^T @ updated_tile`` on the MXU into the (B, D) output
-   (the bandwidth-optimal TPU gather — see kb_gather.py).
+2. dequantizes the tile and computes the outlier-clipped cached-gradient
+   average (``pending_delta`` semantics, same formula as
+   ``repro.core.knowledge_bank``),
+3. re-quantizes the rows that changed and writes back the codes, the
+   per-row (scale, offset) and the zeroed caches of touched rows,
+4. accumulates ``onehot^T @ dequantized_tile`` on the MXU into the (B, D)
+   output.
 
 Grid: bank tiles, sequential; the (B, D) result block stays resident in
-VMEM across the grid and accumulates every tile's contribution.
-Version counters are (N,) int32 metadata — the caller bumps them with a
-cheap jnp scatter (see ``repro.core.kb_engine.PallasBackend``); fusing them
-here would save nothing measurable against the (N, D) streams.
+VMEM across the grid and accumulates every tile's contribution. Version
+counters are (N,) int32 metadata — the caller bumps them with a jnp
+scatter (see ``repro.core.kb_engine.KBEngine``).
 
 ids are padded with -1 (matches no row). Duplicate ids are deterministic:
 every occurrence reads the same updated row.
@@ -66,98 +70,6 @@ def _accumulate_rows(o_ref, hits, rows):
             hits[:, lo:lo + n], rows, (((0,), (0,)), ((), ())),
             precision=HIGHEST, preferred_element_type=jnp.float32)
 
-
-def _fused_kernel(idr_ref, tbl_ref, gsum_ref, gcnt_ref, gsq_ref,
-                  o_tbl_ref, o_gsum_ref, o_gcnt_ref, o_gsq_ref, o_vals_ref,
-                  *, n_block: int, lazy_lr: float, zmax: float):
-    j = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _():
-        o_vals_ref[...] = jnp.zeros_like(o_vals_ref)
-
-    hits, touched = _membership(idr_ref, j, n_block)
-
-    tbl = tbl_ref[...].astype(jnp.float32)                  # (NB, D)
-    gsum = gsum_ref[...]
-    gcnt = gcnt_ref[...]                                    # (NB, 1)
-    gsq = gsq_ref[...]
-
-    # pending_delta, verbatim semantics of the dense reference
-    cnt = jnp.maximum(gcnt, 1.0)
-    avg = gsum / cnt
-    avg_norm = jnp.sqrt(jnp.sum(avg * avg, -1, keepdims=True))
-    rms = jnp.sqrt(gsq / cnt)
-    cap = zmax * jnp.maximum(rms, 1e-12)
-    scale = jnp.minimum(1.0, cap / jnp.maximum(avg_norm, 1e-12))
-    apply = touched & (gcnt > 0)
-    new_tbl = jnp.where(apply, tbl - lazy_lr * avg * scale, tbl)
-
-    o_tbl_ref[...] = new_tbl.astype(o_tbl_ref.dtype)
-    o_gsum_ref[...] = jnp.where(touched, 0.0, gsum)
-    o_gcnt_ref[...] = jnp.where(touched, 0.0, gcnt)
-    o_gsq_ref[...] = jnp.where(touched, 0.0, gsq)
-    _accumulate_rows(o_vals_ref, hits, new_tbl)
-
-
-def kb_fused_lookup_pallas(table, grad_sum, grad_cnt, grad_sqnorm, ids, *,
-                           lazy_lr: float = 0.1, zmax: float = 3.0,
-                           n_block: Optional[int] = None,
-                           interpret: Optional[bool] = None):
-    """table/grad_sum: (N, D); grad_cnt/grad_sqnorm: (N,); ids: (B,) int32.
-
-    Returns (vals (B, D) f32, new_table, new_grad_sum, new_grad_cnt,
-    new_grad_sqnorm) — ``kb_lookup(..., apply_pending=True)`` semantics for
-    everything except the version counter (bumped by the caller).
-    ``interpret``/``n_block`` default to the process `KernelConfig`
-    (repro.env); the bank tile shrinks with the batch so the (n_block, B)
-    one-hot + resident (B, D) output stay inside the VMEM budget (legal tiles
-    for serving batches > 4k ids)."""
-    interpret = resolve_interpret(interpret)
-    N, D = table.shape
-    B = ids.shape[0]
-    if n_block is None:
-        n_block = fused_lookup_block(B, D)
-    nb = min(n_block, N)
-    Bp = -(-B // 8) * 8
-    Np = -(-N // nb) * nb
-    idp = jnp.pad(ids.astype(jnp.int32), (0, Bp - B), constant_values=-1)
-    pad = lambda a: jnp.pad(a, ((0, Np - N),) + ((0, 0),) * (a.ndim - 1))
-    cnt2 = grad_cnt[:, None]
-    sq2 = grad_sqnorm[:, None]
-    kern = functools.partial(_fused_kernel, n_block=nb, lazy_lr=lazy_lr,
-                             zmax=zmax)
-    out = pl.pallas_call(
-        kern,
-        grid=(Np // nb,),
-        in_specs=[pl.BlockSpec((1, Bp), lambda j: (0, 0)),
-                  pl.BlockSpec((nb, D), lambda j: (j, 0)),
-                  pl.BlockSpec((nb, D), lambda j: (j, 0)),
-                  pl.BlockSpec((nb, 1), lambda j: (j, 0)),
-                  pl.BlockSpec((nb, 1), lambda j: (j, 0))],
-        out_specs=[pl.BlockSpec((nb, D), lambda j: (j, 0)),
-                   pl.BlockSpec((nb, D), lambda j: (j, 0)),
-                   pl.BlockSpec((nb, 1), lambda j: (j, 0)),
-                   pl.BlockSpec((nb, 1), lambda j: (j, 0)),
-                   pl.BlockSpec((Bp, D), lambda j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((Np, D), table.dtype),
-                   jax.ShapeDtypeStruct((Np, D), jnp.float32),
-                   jax.ShapeDtypeStruct((Np, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((Np, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((Bp, D), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-        name="kb_fused_lookup",
-    )(idp[None, :], pad(table), pad(grad_sum), pad(cnt2),
-      pad(sq2))
-    new_tbl, gsum, gcnt, gsq, vals = out
-    return (vals[:B], new_tbl[:N], gsum[:N], gcnt[:N, 0], gsq[:N, 0])
-
-
-# ---------------------------------------------------------------------------
-# quantized variant: int8 codes + per-row (scale, offset), dequant fused
-# ---------------------------------------------------------------------------
 
 def _fused_kernel_q(idr_ref, tbl_ref, scl_ref, off_ref, gsum_ref, gcnt_ref,
                     gsq_ref, o_tbl_ref, o_scl_ref, o_off_ref, o_gsum_ref,
@@ -221,13 +133,15 @@ def kb_fused_lookup_q_pallas(table, qscale, qoffset, grad_sum, grad_cnt,
                              n_block: Optional[int] = None,
                              interpret: Optional[bool] = None):
     """Quantized fused lookup. table: (N, D) int8 codes; qscale/qoffset:
-    (N,) f32 per-row affine; caches as in ``kb_fused_lookup_pallas``.
+    (N,) f32 per-row affine; grad_sum: (N, D) f32; grad_cnt/grad_sqnorm:
+    (N,) f32; ids: (B,) int32.
 
     Returns (vals (B, D) f32, new_table int8, new_qscale, new_qoffset,
     new_grad_sum, new_grad_cnt, new_grad_sqnorm) — ``kb_lookup_q``
     semantics except the version counter (bumped by the caller).
-    ``interpret``/``n_block`` resolve from the process `KernelConfig`
-    exactly as in ``kb_fused_lookup_pallas``."""
+    ``interpret``/``n_block`` default to the process `KernelConfig`
+    (repro.env); the bank tile shrinks with the batch so the (n_block, B)
+    one-hot and the resident (B, D) output stay inside the VMEM budget."""
     interpret = resolve_interpret(interpret)
     N, D = table.shape
     B = ids.shape[0]

@@ -100,6 +100,87 @@ def test_pallas_fused_lookup_is_one_call_semantics():
     assert float(kb_p.grad_cnt.sum()) == 0.0
 
 
+# Each case: the looked-up ids, the rows holding pending gradients (one
+# unit-scale batch each, then the case's extra batch), and the lookup's
+# zmax. The apply-side clip engages only for zmax < 1: the average of a
+# row's cached gradients is never longer than their rms.
+_LOOKUP_CASES = {
+    "repeated_ids": ([7, 7, 9, 7, 9, 30], [7, 9], ZMAX),
+    "pow2_padded": ([3, 11, 12, 5, 40, 40, 40, 40], [3, 5, 40], ZMAX),
+    "pending_and_clean": ([1, 2, 3, 4, 5, 6], [2, 4, 6], ZMAX),
+    "outlier_clip": ([21, 22, 23], [21, 22], 0.5),
+    "first_and_last_row": ([0, N - 1, 0], [0, N - 1], ZMAX),
+}
+
+
+@pytest.mark.parametrize("case", list(_LOOKUP_CASES))
+def test_pallas_lookup_gathers_only_requested_rows(case):
+    """``PallasBackend.lookup`` == ``kb_lookup(apply_pending=True)`` bit for
+    bit; the requested rows read the float64 clipped pending average, every
+    occurrence of an id the same row; every row outside the ids — table,
+    caches, ``norm_ema``, version — is bit-identical before and after."""
+    ids_l, pending, zmax = _LOOKUP_CASES[case]
+    ids = jnp.asarray(ids_l, jnp.int32)
+    kb = kb_create(N, D, key=jax.random.key(11))
+    rng = np.random.default_rng(12)
+    pend = jnp.asarray(pending, jnp.int32)
+    g = rng.normal(size=(len(pending), D)).astype(np.float32)
+    kb = kb_lazy_grad(kb, pend, jnp.asarray(g), zmax=ZMAX)
+    # a second batch: the first pending row gets a 10x outlier, which the
+    # entry-side clip cuts against the norm EMA the first batch seeded
+    g2 = rng.normal(size=(len(pending), D)).astype(np.float32)
+    g2[0] *= 10.0
+    kb = kb_lazy_grad(kb, pend, jnp.asarray(g2), zmax=ZMAX)
+    before = jax.tree.map(np.asarray, kb)
+
+    v_p, kb_p = PallasBackend().lookup(kb, ids, lazy_lr=LAZY_LR, zmax=zmax)
+    v_d, kb_d = kb_lookup(kb, ids, lazy_lr=LAZY_LR, zmax=zmax)
+    np.testing.assert_array_equal(np.asarray(v_p), np.asarray(v_d))
+    for leaf_p, leaf_d in zip(kb_p, kb_d):
+        np.testing.assert_array_equal(np.asarray(leaf_p), np.asarray(leaf_d))
+    if case == "pow2_padded":
+        # the engine pads with the last real id: the pads change nothing
+        v_u, kb_u = kb_lookup(kb, ids[:5], lazy_lr=LAZY_LR, zmax=zmax)
+        np.testing.assert_array_equal(np.asarray(v_p)[:5], np.asarray(v_u))
+        for leaf_p, leaf_u in zip(kb_p, kb_u):
+            np.testing.assert_array_equal(np.asarray(leaf_p),
+                                          np.asarray(leaf_u))
+
+    after = jax.tree.map(np.asarray, kb_p)
+    touched = np.unique(ids_l)
+    out = np.setdiff1d(np.arange(N), touched)
+    for name in ("table", "grad_sum", "grad_cnt", "grad_sqnorm", "version"):
+        np.testing.assert_array_equal(getattr(after, name)[out],
+                                      getattr(before, name)[out],
+                                      err_msg=name)
+    np.testing.assert_array_equal(after.norm_ema, before.norm_ema)
+    assert not after.grad_sum[touched].any()
+    assert not after.grad_cnt[touched].any()
+    assert not after.grad_sqnorm[touched].any()
+    had = before.grad_cnt[touched] > 0
+    np.testing.assert_array_equal(after.version[touched],
+                                  before.version[touched] + had)
+
+    # the pending apply in float64, from the state before the call
+    cnt = np.maximum(before.grad_cnt, 1.0)[:, None].astype(np.float64)
+    avg = before.grad_sum / cnt
+    nrm = np.linalg.norm(avg, axis=-1, keepdims=True)
+    cap = zmax * np.sqrt(before.grad_sqnorm[:, None] / cnt)
+    scale = np.minimum(1.0, cap / np.maximum(nrm, 1e-12))
+    want = np.where(before.grad_cnt[:, None] > 0,
+                    before.table - LAZY_LR * avg * scale, before.table)
+    vals = np.asarray(v_p)
+    np.testing.assert_allclose(vals, want[ids_l], rtol=0, atol=1e-6)
+    np.testing.assert_array_equal(after.table[touched], vals[
+        [ids_l.index(i) for i in touched]])
+    for i in touched:
+        first = vals[ids_l.index(i)]
+        for j in np.flatnonzero(np.asarray(ids_l) == i):
+            np.testing.assert_array_equal(vals[j], first)
+    if case == "outlier_clip":
+        assert scale[ids_l[0], 0] < 1.0      # the apply-side clip ran
+
+
 @pytest.mark.parametrize("backend", ["dense", "pallas"])
 def test_engine_bucket_padding_is_invisible(backend):
     """Engine results at awkward batch sizes (pow2-padded internally) match

@@ -156,7 +156,7 @@ def _args(eng, name):
 
 
 @pytest.mark.parametrize("storage,attr,jit_name,kernel", [
-    ("fp32", "_lookup_fn", "kb_lookup", "kb_fused_lookup"),
+    ("fp32", "_lookup_fn", "kb_lookup", None),
     ("int8", "_lookup_fn", "kb_lookup_q", "kb_fused_lookup_q"),
     ("fp32", "_update_fn", "kb_update", None),
     ("fp32", "_lazy_fn", "kb_lazy_grad", None),

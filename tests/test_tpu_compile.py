@@ -1,7 +1,8 @@
 """The bank's Pallas kernels compiled for a described TPU v5e, at the widths
 ``chip_smoke.py`` serves (a 2^20 x 128 bank, 512-id batches, an IVF index
-of 1024 lists probed 32 at a time), plus one sharded lookup compiled for a
-v5e 2x2 mesh.
+of 1024 lists probed 32 at a time), the engine's fp32 lookup program at
+the batch sizes the server coalesces, plus one sharded lookup compiled for
+a v5e 2x2 mesh.
 
 Nothing runs. The TPU compiler is installed without a chip and refuses
 what the chip would refuse: block shapes off the (8, 128) tiling, scoped
@@ -24,10 +25,10 @@ from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import sharded_kb as skb
+from repro.core.kb_engine import KBEngine
 from repro.core.knowledge_bank import KBState
 from repro.kernels import nn_search_ivf as ivf
-from repro.kernels.kb_fused_lookup import (kb_fused_lookup_pallas,
-                                           kb_fused_lookup_q_pallas)
+from repro.kernels.kb_fused_lookup import kb_fused_lookup_q_pallas
 from repro.kernels.kb_gather import kb_gather_pallas
 from repro.kernels.lazy_apply import lazy_apply_pallas
 from repro.kernels.nn_search import nn_search_pallas
@@ -74,10 +75,6 @@ def _kernel_cases(batch):
     q = ((batch, D), f32)
     occ = ((NLIST,), i32)
     return {
-        "fused_lookup": (
-            lambda t, g, c, s, i: kb_fused_lookup_pallas(
-                t, g, c, s, i, interpret=False),
-            [((N, D), f32), ((N, D), f32), ((N,), f32), ((N,), f32), ids]),
         "fused_lookup_q": (
             lambda t, sc, of, g, c, s, i: kb_fused_lookup_q_pallas(
                 t, sc, of, g, c, s, i, interpret=False),
@@ -116,8 +113,7 @@ def _kernel_cases(batch):
     }
 
 
-_CASES = [(name, B) for name in _kernel_cases(B)] + [
-    ("fused_lookup", 8), ("fused_lookup", 4096), ("gather", 4096)]
+_CASES = [(name, B) for name in _kernel_cases(B)] + [("gather", 4096)]
 
 
 @pytest.mark.parametrize("name,batch", _CASES,
@@ -127,6 +123,25 @@ def test_bank_kernel_compiles_for_v5e(one_chip, name, batch):
     args = [_spec(one_chip, s, d) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch", [8, 512, 2048, 4096])
+def test_fp32_lookup_compiles_for_v5e(one_chip, batch):
+    """The engine's fp32 lookup program on the Pallas backend gathers and
+    scatters its rows by id: its FLOPs follow the batch (a one-hot gather
+    over the bank does 2·N·B·D, 5.5e11 at 2,048 ids), and no (N, 1)
+    column, which the chip pads to 128 lanes, appears anywhere in it."""
+    eng = KBEngine(8, D, backend="pallas", interpret=False)
+    dtypes = KBState(*([jnp.float32, jnp.int32] + [jnp.float32] * 4
+                       + [jnp.int32]))
+    shapes = KBState(table=(N, D), version=(N,), grad_sum=(N, D),
+                     grad_cnt=(N,), grad_sqnorm=(N,), norm_ema=(N,),
+                     step=())
+    state = KBState(*[_spec(one_chip, s, d) for s, d in zip(shapes, dtypes)])
+    ids = _spec(one_chip, (batch,), jnp.int32)
+    compiled = eng._lookup_fn.lower(state, ids).compile()
+    assert compiled.cost_analysis()["flops"] < 1e9
+    assert f"f32[{N},1]" not in compiled.as_text()
 
 
 def test_sharded_lookup_compiles_for_v5e_2x2(topo):
