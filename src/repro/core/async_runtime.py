@@ -368,6 +368,7 @@ class KnowledgeBankServer:
         m["tier_faults"] = storage["tier_faults"]
         m["tier_spills"] = storage["tier_spills"]
         m["engine_op_s"] = self.engine.op_s
+        m["engine_args_s"] = self.engine.args_s
         m["engine_wait_s"] = self.engine.wait_s
         return {
             "metrics": m,
@@ -1234,14 +1235,10 @@ def run_async_training(model: LM, corpus: SyntheticGraphCorpus, *,
                              f"d_model {cfg.d_model}")
         server = kb_client
     else:
-        kb_dist = None
-        if kb_backend == "sharded":
-            # the bank gets its own meshed context (the trainer's stays
-            # as-is)
-            from repro.launch.mesh import make_host_mesh
-            kb_dist = DistContext(mesh=make_host_mesh())
+        # a sharded bank spans every local device (the trainer's mesh stays
+        # as-is)
         server = KnowledgeBankServer(
-            corpus.num_nodes, cfg.d_model, backend=kb_backend, dist=kb_dist,
+            corpus.num_nodes, cfg.d_model, backend=kb_backend,
             lazy_lr=cfg.carls.lazy_lr, zmax=cfg.carls.outlier_zmax,
             lazy_update=lazy_update, coalesce=coalesce)
     ckpts = MemoryCheckpointStore()

@@ -72,7 +72,7 @@ import numpy as np
 from repro.core import knowledge_bank as kbm
 from repro.core.kb_storage import make_cold_store
 from repro.core.knowledge_bank import KBState
-from repro.sharding.partition import DistContext
+from repro.sharding.partition import DistContext, local_dist
 
 
 class KBBackend(Protocol):
@@ -119,14 +119,17 @@ class DenseBackend:
 
 class ShardedBackend:
     """Mesh-sharded ops: owner-masked scatters, one psum fan-in per lookup.
-    See repro.core.sharded_kb for the communication analysis."""
+    See repro.core.sharded_kb for the communication analysis. Without a
+    ``DistContext`` that carries a mesh, the bank spans every local device
+    (``repro.sharding.partition.local_dist``)."""
 
     name = "sharded"
 
-    def __init__(self, dist: DistContext, *, use_nn_kernel: bool = False):
+    def __init__(self, dist: Optional[DistContext] = None, *,
+                 use_nn_kernel: bool = False):
         from repro.core import sharded_kb as skb
         if dist is None or dist.mesh is None:
-            raise ValueError("ShardedBackend needs a DistContext with a mesh")
+            dist = local_dist()
         self.dist = dist
         self.use_nn_kernel = use_nn_kernel
         self._skb = skb
@@ -485,9 +488,11 @@ class KBEngine:
         # re-ranking in int8 mode; invalidated per-id by lazy_grad
         self._masters: "OrderedDict[int, np.ndarray]" = OrderedDict()
         self.dispatches = 0         # device calls issued (bench metric)
-        # host seconds inside the op methods, and the part of them spent
-        # blocked on a result's device-to-host copy (``kb.engine.wait``)
+        # host seconds inside the op methods, and the parts of them spent
+        # placing arguments on the device (``kb.engine.args``) and blocked
+        # on a result's device-to-host copy (``kb.engine.wait``)
         self.op_s = 0.0
+        self.args_s = 0.0
         self.wait_s = 0.0
         # the server's run number while it calls an op (-1: a direct
         # call); tags the ``kb.engine.*`` spans
@@ -558,6 +563,18 @@ class KBEngine:
         self._immediate_fn = jax.jit(kb_immediate_grad)
         self._nn_fns = {}
 
+    def _put(self, *arrays) -> tuple:
+        """Place an op's host arguments on the device, inside a
+        ``kb.engine.args`` span tagged with the run and the bank's shard
+        count, and add the time to ``args_s``."""
+        t = time.perf_counter()
+        with jax.profiler.TraceAnnotation("kb.engine.args",
+                                          run=self.current_run,
+                                          shards=self.ann_shards):
+            out = tuple(jnp.asarray(a) for a in arrays)
+        self.args_s += time.perf_counter() - t
+        return out
+
     def _fetch(self, *arrays) -> tuple:
         """Copy an op's results to the host: its one wait on the device,
         inside a ``kb.engine.wait`` span and added to ``wait_s``."""
@@ -583,12 +600,12 @@ class KBEngine:
         dev = self._admit(flat)
         pad = _bucket(dev.size) - dev.size
         padded = np.concatenate([dev, np.full(pad, dev[-1], np.int32)])
+        (padded,) = self._put(padded)
         if self._quantized:
             vals, self.state, self._qscale, self._qoffset = self._lookup_fn(
-                self.state, self._qscale, self._qoffset, jnp.asarray(padded))
+                self.state, self._qscale, self._qoffset, padded)
         else:
-            vals, self.state = self._lookup_fn(self.state,
-                                               jnp.asarray(padded))
+            vals, self.state = self._lookup_fn(self.state, padded)
         self.dispatches += 1
         (vals,) = self._fetch(vals[:flat.size])
         return vals.reshape(*ids.shape, -1)
@@ -620,13 +637,12 @@ class KBEngine:
         pad = _bucket(n) - n
         dev_p = np.concatenate([dev, np.full(pad, dev[-1], np.int32)])
         values_p = np.concatenate([values, np.repeat(values[-1:], pad, 0)])
+        dev_p, values_p = self._put(dev_p, values_p)
         if self._quantized:
             self.state, self._qscale, self._qoffset = self._update_fn(
-                self.state, self._qscale, self._qoffset,
-                jnp.asarray(dev_p), jnp.asarray(values_p))
+                self.state, self._qscale, self._qoffset, dev_p, values_p)
         else:
-            self.state = self._update_fn(self.state, jnp.asarray(dev_p),
-                                         jnp.asarray(values_p))
+            self.state = self._update_fn(self.state, dev_p, values_p)
         self.dispatches += 1
         self._count_writes(ids)
         if self.tiered:
@@ -658,8 +674,7 @@ class KBEngine:
         mask = np.concatenate([np.ones(n, np.float32),
                                np.zeros(pad, np.float32)])
         fn = self._lazy_fn if self.lazy_update else self._immediate_fn
-        self.state = fn(self.state, jnp.asarray(ids_p), jnp.asarray(grads_p),
-                        jnp.asarray(mask))
+        self.state = fn(self.state, *self._put(ids_p, grads_p, mask))
         self.dispatches += 1
         # row mutation volume for ANN staleness: a cached gradient WILL be
         # applied (next lookup or flush), immediate mode scatters now —

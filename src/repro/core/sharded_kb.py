@@ -133,6 +133,10 @@ def sharded_kb_lookup(kb: KBState, ids: jnp.ndarray, dist: DistContext, *,
             gsum = own.set(gsum, jnp.zeros_like(rows))
             gcnt = own.set(gcnt, jnp.zeros_like(cnt))
             gsq = own.set(gsq, jnp.zeros_like(cnt))
+            # reply with the rows as stored: XLA may fuse the arithmetic
+            # above into both the scatter and the psum's operand and round
+            # the two copies differently (an FMA in one fusion only)
+            rows = own.gather(table)
         vals = jax.lax.psum(own.mask(rows), axes)
         return vals, table, version, gsum, gcnt, gsq
 

@@ -14,7 +14,8 @@ greedy decode. Full-size serve programs (decode_32k / long_500k) are
 exercised via the dry-run lowering of the same ``decode_step``.
 
 KB mode stands up the request-coalescing KnowledgeBankServer on the chosen
-engine backend (dense | pallas | sharded — sharded gets a host mesh) and
+engine backend (dense | pallas | sharded — the sharded bank's rows span a
+(1, n) mesh over every local device, which the backend builds itself) and
 drives it with concurrent lookup/lazy_grad/nn_search clients — the Figure-1
 serving topology without the trainer attached. ``--kb-search ivf`` serves
 nn_search from the asynchronously-clustered IVF index, rebuilt by a
@@ -63,7 +64,8 @@ from repro.sharding.partition import DistContext
 
 def format_kb_timing(m: dict) -> str:
     """Mean queue wait per request, and the dispatcher's and the engine's
-    host time per device dispatch, from a server's (or a fleet's summed)
+    host time per device dispatch, the engine's placing of arguments on
+    the device among it, from a server's (or a fleet's summed)
     ``stats()["metrics"]``."""
     req = max(m.get("requests", 0), 1)
     disp = max(m.get("dispatches", 0), 1)
@@ -71,9 +73,11 @@ def format_kb_timing(m: dict) -> str:
     dispatcher = (m.get("dispatcher_busy_s", 0.0)
                   - m.get("engine_call_s", 0.0)) / disp
     engine = (m.get("engine_op_s", 0.0) - m.get("engine_wait_s", 0.0)) / disp
+    args = m.get("engine_args_s", 0.0) / disp
     return (f"kb host time: queue wait {wait * 1e3:.3f} ms/request, "
             f"dispatcher {dispatcher * 1e3:.3f} ms/dispatch, "
-            f"engine {engine * 1e3:.3f} ms/dispatch")
+            f"engine {engine * 1e3:.3f} ms/dispatch "
+            f"(args {args * 1e3:.3f})")
 
 
 def serve_kb_partitioned(args) -> None:
@@ -191,10 +195,6 @@ def serve_kb(args) -> dict:
     from repro.core import (KnowledgeBankServer, MakerRuntime,
                             format_maker_stats)
     rng = np.random.default_rng(args.seed)
-    dist = None
-    if args.kb_backend == "sharded":
-        from repro.launch.mesh import make_host_mesh
-        dist = DistContext(mesh=make_host_mesh())
     partition_label = ""
     num_rows = args.kb_entries
     fill_ids = np.arange(args.kb_entries)
@@ -219,7 +219,7 @@ def serve_kb(args) -> dict:
         # fleet's initial table matches a single server's row-for-row
         fill_ids = pmap.global_ids(idx)
     server = KnowledgeBankServer(num_rows, args.kb_dim,
-                                 backend=args.kb_backend, dist=dist,
+                                 backend=args.kb_backend,
                                  coalesce=not args.no_coalesce,
                                  reorder=args.kb_reorder,
                                  search_mode=args.kb_search,

@@ -14,6 +14,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -68,6 +69,13 @@ def make_dist(mesh: Optional[Mesh]) -> DistContext:
         return DistContext()
     pod = "pod" if "pod" in mesh.axis_names else None
     return DistContext(mesh=mesh, pod_axis=pod)
+
+
+def local_dist() -> DistContext:
+    """A (1, n) ``data`` x ``model`` mesh over every local device: the
+    mesh a sharded Knowledge Bank spans when its caller gives none."""
+    devices = np.asarray(jax.local_devices()).reshape(1, -1)
+    return DistContext(mesh=Mesh(devices, ("data", "model")))
 
 
 # ---------------------------------------------------------------------------

@@ -197,10 +197,11 @@ def test_jitted_engine_functions_have_stable_names(storage, attr, jit_name,
 # -- the profiler changes nothing ------------------------------------------
 
 
-@pytest.mark.parametrize("backend", ["dense", "pallas"])
+@pytest.mark.parametrize("backend", ["dense", "pallas", "sharded"])
 def test_results_identical_with_and_without_profiler(backend, tmp_path):
     def serial(profiled: bool):
         srv = _filled_server(backend, coalesce=False)
+        args_before = srv.stats()["metrics"]["engine_args_s"]
         if profiled:
             jax.profiler.start_trace(str(tmp_path))
         try:
@@ -213,10 +214,27 @@ def test_results_identical_with_and_without_profiler(backend, tmp_path):
             if profiled:
                 jax.profiler.stop_trace()
             srv.close()
+        assert srv.stats()["metrics"]["engine_args_s"] > args_before
         return results, table
 
     (plain, t_plain), (traced, t_traced) = serial(False), serial(True)
     np.testing.assert_array_equal(t_plain, t_traced)
+    # the engine's argument placement is a span of its own inside each
+    # lookup and lazy_grad, tagged with that op's run and the shard count
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                         "*", "*.xplane.pb")))[-1]
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("kb.engine.")]
+    args = [s for s in spans if s[0] == "kb.engine.args"]
+    ops = [s for s in spans
+           if s[0] in ("kb.engine.lookup", "kb.engine.lazy_grad")]
+    assert len(args) == len(ops) == 2 * CALLS * 2 // 3
+    for a in args:
+        (op,) = [o for o in ops if _inside(a, o)]
+        assert a[3] == {"run": op[3]["run"], "shards": 1}
     assert len(plain) == len(traced)
     for a, b in zip(plain, traced):
         assert a[:2] == b[:2]
