@@ -481,11 +481,14 @@ class IVFRefresher(threading.Thread):
     one hot shard never forces a full-bank rebuild. ``shard_rebuilds``
     counts sub-index builds; ``rebuilds`` counts swap operations.
 
-    Thread-safety contract: reads of ``engine.state`` / writes of
-    ``engine.ann_index`` are safe against the single-threaded engine owner
-    (the server's dispatcher) because states are immutable pytrees and both
-    fields are plain attribute stores — see ``KBEngine.set_ann_index`` for
-    the ordering argument that makes a torn read harmless."""
+    Thread-safety contract: the engine's ops donate the bank state, so a
+    table read unguarded could be deleted under the refresher by the
+    dispatcher's next op. ``KBEngine.rebuild_ann_index`` therefore copies
+    the table on the device under ``engine.swap_lock``, which every
+    donating dispatch also holds, and moves the copy to the host outside
+    it. The write of ``engine.ann_index`` is a plain attribute store of an
+    immutable value — see ``KBEngine.set_ann_index`` for the ordering
+    argument that makes a torn read harmless."""
 
     def __init__(self, engine, *, rebuild_rows: Optional[int] = None,
                  rebuild_shard_rows: Optional[int] = None,

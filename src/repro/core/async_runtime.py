@@ -370,6 +370,7 @@ class KnowledgeBankServer:
         m["engine_op_s"] = self.engine.op_s
         m["engine_args_s"] = self.engine.args_s
         m["engine_wait_s"] = self.engine.wait_s
+        m["donation_misses"] = self.engine.donation_misses
         return {
             "metrics": m,
             "mean_staleness": float(self.mean_staleness),

@@ -46,21 +46,36 @@ its staleness budget. On the sharded backend the engine maintains a
 per-shard independent rebuilds — and serves IVF queries through the
 hierarchical merge in ``repro.core.sharded_kb.sharded_kb_nn_search_ivf``.
 
+State ownership: lookup, update and lazy_grad DONATE the bank state to
+their jitted program, which scatters its B rows in place and returns the
+new state; the old state's buffers are deleted. Only the engine's current
+``state`` is live, and a caller that wants an older one must copy it
+before the next op. ``donation_misses`` counts the dispatches after which
+a leaf of the donated state was not consumed (XLA did not alias it, or a
+host view held it). It cannot see a copy made inside a program that
+aliases its input, as the int8 fused lookup's Pallas kernel, which
+declares no ``input_output_aliases``, makes of ``table`` and ``grad_sum``;
+the compile tests and the device trace show those. ``flush`` and the
+tiered fault-in and ``import_rows`` scatters do not donate.
+
 The engine itself is NOT thread-safe — concurrency (locking or request
 coalescing) is the server layer's job. The one sanctioned exception: the
-``IVFRefresher`` thread reads ``state`` / ``total_write_rows`` /
-``shard_write_rows`` and swaps ``ann_index``. ``state`` and ``ann_index``
-are atomic attribute stores of immutable values; ``shard_write_rows`` is
-a numpy array the owner mutates in place (monotonic ``+=``), so the
-refresher may read a value stale by the in-flight batch — which only
-UNDERSTATES staleness by that batch, deferring (never corrupting) a
-rebuild, and the post-build clock snapshot is taken before the table
-read so concurrent writes still count as staleness against the new
-index.
+``IVFRefresher`` thread snapshots the table, reads ``total_write_rows`` /
+``shard_write_rows`` and swaps ``ann_index``. The snapshot is an on-device
+copy taken under ``swap_lock``, which every donating dispatch also holds,
+so no donation can delete the table mid-read; the copy moves to the host
+outside the lock. ``ann_index`` is an atomic attribute store of an
+immutable value; ``shard_write_rows`` is a numpy array the owner mutates
+in place (monotonic ``+=``), so the refresher may read a value stale by
+the in-flight batch — which only UNDERSTATES staleness by that batch,
+deferring (never corrupting) a rebuild, and the post-build clock snapshot
+is taken before the table read so concurrent writes still count as
+staleness against the new index.
 """
 from __future__ import annotations
 
 import functools
+import threading
 import time
 from collections import OrderedDict
 from typing import Callable, NamedTuple, Optional, Protocol, Tuple
@@ -497,6 +512,10 @@ class KBEngine:
         # the server's run number while it calls an op (-1: a direct
         # call); tags the ``kb.engine.*`` spans
         self.current_run = -1
+        # held by every dispatch that donates the state, and by the IVF
+        # refresher while it copies the table (module docstring)
+        self.swap_lock = threading.Lock()
+        self.donation_misses = 0
 
         bk = self.backend
         if self._quantized:
@@ -530,7 +549,7 @@ class KBEngine:
             def kb_flush(st, qs, qo):
                 return kbm.kb_flush_q(st, qs, qo, lazy_lr=lazy_lr, zmax=zmax)
 
-            self._lookup_fn = jax.jit(kb_lookup_q)
+            self._lookup_fn = jax.jit(kb_lookup_q, donate_argnums=(0, 1, 2))
         else:
             def kb_lookup(st, ids):
                 return bk.lookup(st, ids, lazy_lr=lazy_lr, zmax=zmax,
@@ -542,10 +561,12 @@ class KBEngine:
             def kb_flush(st):
                 return bk.flush(st, lazy_lr=lazy_lr, zmax=zmax)
 
-            self._lookup_fn = jax.jit(kb_lookup)
+            self._lookup_fn = jax.jit(kb_lookup, donate_argnums=0)
         # each jitted op is a named function, not a lambda: XLA names the
-        # device ops in a profile after it
-        self._update_fn = jax.jit(kb_update)
+        # device ops in a profile after it. Every op that returns a new
+        # state donates the old one (module docstring), except flush
+        self._update_fn = jax.jit(
+            kb_update, donate_argnums=(0, 1, 2) if self._quantized else 0)
         self._flush_fn = jax.jit(kb_flush)
 
         # lazy_grad only touches the fp32 gradient caches — never the table
@@ -559,8 +580,8 @@ class KBEngine:
             return st._replace(table=st.table.at[ids].add(
                 (-lazy_lr * g * m[:, None]).astype(st.table.dtype)))
 
-        self._lazy_fn = jax.jit(kb_lazy_grad)
-        self._immediate_fn = jax.jit(kb_immediate_grad)
+        self._lazy_fn = jax.jit(kb_lazy_grad, donate_argnums=0)
+        self._immediate_fn = jax.jit(kb_immediate_grad, donate_argnums=0)
         self._nn_fns = {}
 
     def _put(self, *arrays) -> tuple:
@@ -584,6 +605,13 @@ class KBEngine:
         self.wait_s += time.perf_counter() - t
         return out
 
+    def _count_miss(self, donated) -> None:
+        """After a dispatch that donated ``donated``: count a miss if any
+        of its leaves was not consumed (XLA did not alias it, or a host
+        view held it)."""
+        if not all(a.is_deleted() for a in jax.tree.leaves(donated)):
+            self.donation_misses += 1
+
     # -- embedding ops -----------------------------------------------------
 
     @_engine_op
@@ -601,11 +629,14 @@ class KBEngine:
         pad = _bucket(dev.size) - dev.size
         padded = np.concatenate([dev, np.full(pad, dev[-1], np.int32)])
         (padded,) = self._put(padded)
-        if self._quantized:
-            vals, self.state, self._qscale, self._qoffset = self._lookup_fn(
-                self.state, self._qscale, self._qoffset, padded)
-        else:
-            vals, self.state = self._lookup_fn(self.state, padded)
+        donated = (self.state, self._qscale, self._qoffset)
+        with self.swap_lock:
+            if self._quantized:
+                vals, self.state, self._qscale, self._qoffset = (
+                    self._lookup_fn(*donated, padded))
+            else:
+                vals, self.state = self._lookup_fn(self.state, padded)
+        self._count_miss(donated)
         self.dispatches += 1
         (vals,) = self._fetch(vals[:flat.size])
         return vals.reshape(*ids.shape, -1)
@@ -638,11 +669,14 @@ class KBEngine:
         dev_p = np.concatenate([dev, np.full(pad, dev[-1], np.int32)])
         values_p = np.concatenate([values, np.repeat(values[-1:], pad, 0)])
         dev_p, values_p = self._put(dev_p, values_p)
-        if self._quantized:
-            self.state, self._qscale, self._qoffset = self._update_fn(
-                self.state, self._qscale, self._qoffset, dev_p, values_p)
-        else:
-            self.state = self._update_fn(self.state, dev_p, values_p)
+        donated = (self.state, self._qscale, self._qoffset)
+        with self.swap_lock:
+            if self._quantized:
+                self.state, self._qscale, self._qoffset = self._update_fn(
+                    *donated, dev_p, values_p)
+            else:
+                self.state = self._update_fn(self.state, dev_p, values_p)
+        self._count_miss(donated)
         self.dispatches += 1
         self._count_writes(ids)
         if self.tiered:
@@ -674,7 +708,11 @@ class KBEngine:
         mask = np.concatenate([np.ones(n, np.float32),
                                np.zeros(pad, np.float32)])
         fn = self._lazy_fn if self.lazy_update else self._immediate_fn
-        self.state = fn(self.state, *self._put(ids_p, grads_p, mask))
+        args = self._put(ids_p, grads_p, mask)
+        donated = self.state
+        with self.swap_lock:
+            self.state = fn(donated, *args)
+        self._count_miss(donated)
         self.dispatches += 1
         # row mutation volume for ANN staleness: a cached gradient WILL be
         # applied (next lookup or flush), immediate mode scatters now —
@@ -1070,8 +1108,10 @@ class KBEngine:
     def rebuild_ann_index(self, *, iters: int = 8,
                           shards: Optional[list] = None) -> int:
         """Snapshot -> cluster -> pack -> swap. Safe to call from a
-        background thread: the snapshot read and the final swap are atomic
-        attribute operations; everything between runs on this thread.
+        background thread: the snapshot is an on-device copy of the table
+        made under ``swap_lock`` (so no donating op can delete the table
+        mid-read) and moved to the host outside it; the final swap is an
+        atomic attribute store; everything between runs on this thread.
 
         ``shards`` (sharded backend only): rebuild just those shards'
         sub-indexes, keeping every other sub-index — and its staleness
@@ -1084,15 +1124,16 @@ class KBEngine:
                                           QuantizedShardedIVFIndex,
                                           ShardedIVFIndex, build_ivf_index,
                                           build_sharded_ivf_index)
-        built_at = self.shard_write_rows.copy()  # writes during the build
-        if self._quantized:                      # count as stale
+        # writes during the build count as stale against the new index
+        built_at = self.shard_write_rows.copy()
+        with self.swap_lock:
             # cluster on the dequantized snapshot; the packed buckets then
             # re-quantize per-slot (QuantizedIVFIndex), so stage 2 scores
             # int8 rows and never holds an fp32 copy of the bank
-            table = np.asarray(kbm.dequantize_rows(
-                self.state.table, self._qscale, self._qoffset), np.float32)
-        else:
-            table = np.asarray(self.state.table, np.float32)
+            table = (kbm.dequantize_rows(self.state.table, self._qscale,
+                                         self._qoffset)
+                     if self._quantized else jnp.copy(self.state.table))
+        table = np.asarray(table, np.float32)
         put = (self.backend.put_rows if self.ann_shards > 1
                else jnp.asarray)
         wrap = ((lambda ix: ix) if self.storage != "int8" else
@@ -1128,21 +1169,22 @@ class KBEngine:
 
     def warmup(self, max_batch: int = 256) -> None:
         """Pre-compile the lookup/lazy_grad jit buckets up to ``max_batch``
-        so serving never stalls on a first-request compile (results are
-        discarded; state is untouched)."""
+        so serving never stalls on a first-request compile. The ops donate
+        their state, so they run on a throwaway copy of it, threaded through
+        the calls: the live state is untouched, and the programs compiled
+        are the ones served (same shardings, same donation)."""
+        st, *qsc = jax.tree.map(jnp.copy, (self.state, self._qscale,
+                                           self._qoffset))
+        qsc = qsc if self._quantized else []
+        grad_fn = self._lazy_fn if self.lazy_update else self._immediate_fn
         b = 8
         top = _bucket(max_batch)
         while b <= top:
             ids = jnp.zeros((b,), jnp.int32)
             zeros = jnp.zeros((b, self.dim), jnp.float32)
             mask = jnp.zeros((b,), jnp.float32)
-            if self._quantized:
-                self._lookup_fn(self.state, self._qscale, self._qoffset,
-                                ids)
-            else:
-                self._lookup_fn(self.state, ids)
-            (self._lazy_fn if self.lazy_update
-             else self._immediate_fn)(self.state, ids, zeros, mask)
+            _, st, *qsc = self._lookup_fn(st, *qsc, ids)
+            st = grad_fn(st, ids, zeros, mask)
             b *= 2
 
     # -- introspection -----------------------------------------------------

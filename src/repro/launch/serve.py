@@ -65,7 +65,8 @@ from repro.sharding.partition import DistContext
 def format_kb_timing(m: dict) -> str:
     """Mean queue wait per request, and the dispatcher's and the engine's
     host time per device dispatch, the engine's placing of arguments on
-    the device among it, from a server's (or a fleet's summed)
+    the device among it, and the dispatches whose donated bank state was
+    not consumed, from a server's (or a fleet's summed)
     ``stats()["metrics"]``."""
     req = max(m.get("requests", 0), 1)
     disp = max(m.get("dispatches", 0), 1)
@@ -77,7 +78,8 @@ def format_kb_timing(m: dict) -> str:
     return (f"kb host time: queue wait {wait * 1e3:.3f} ms/request, "
             f"dispatcher {dispatcher * 1e3:.3f} ms/dispatch, "
             f"engine {engine * 1e3:.3f} ms/dispatch "
-            f"(args {args * 1e3:.3f})")
+            f"(args {args * 1e3:.3f}), "
+            f"donation misses {m.get('donation_misses', 0)}")
 
 
 def serve_kb_partitioned(args) -> None:

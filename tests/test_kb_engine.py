@@ -1,7 +1,8 @@
 """KB engine: backend parity (dense == sharded == pallas, bit-for-bit on
-the same op sequence), coalescing-server correctness under concurrency
-(ISSUE 1 acceptance suite), and the IVF search mode — recall, exact
-fallback, coalesced determinism, background refresh (ISSUE 2)."""
+the same op sequence), state donation (every op consumes the state it is
+given; warm-up leaves it alone), coalescing-server correctness under
+concurrency, and the IVF search mode — recall, exact fallback, coalesced
+determinism, background refresh under donating writes."""
 import threading
 import time
 
@@ -13,6 +14,7 @@ import pytest
 from repro.core import (DenseBackend, KBEngine, KnowledgeBankServer,
                         PallasBackend, ShardedBackend, kb_create,
                         kb_lazy_grad, kb_lookup, make_backend)
+from repro.core import knowledge_bank as kbm
 from repro.launch.mesh import make_host_mesh
 from repro.sharding.partition import DistContext
 
@@ -202,6 +204,115 @@ def test_engine_bucket_padding_is_invisible(backend):
                                atol=1e-5)
     np.testing.assert_array_equal(eng.version_snapshot(),
                                   np.asarray(ref.version))
+
+
+def _live(eng):
+    """Every array of the engine's bank state (int8 side-cars included)."""
+    return jax.tree.leaves((eng.state, eng._qscale, eng._qoffset))
+
+
+def _reference_ops(storage, lazy):
+    """The plain functional ops, jitted without donation. Each takes and
+    returns the state as the engine holds it: a tuple of the ``KBState``
+    and, for int8, its scale and offset."""
+    def lookup(st, ids):
+        if storage == "int8":
+            vals, *st = kbm.kb_lookup_q(*st, ids, lazy_lr=LAZY_LR, zmax=ZMAX)
+            return vals, tuple(st)
+        vals, kb = kb_lookup(st[0], ids, lazy_lr=LAZY_LR, zmax=ZMAX,
+                             apply_pending=lazy)
+        return vals, (kb,)
+
+    def update(st, ids, values):
+        if storage == "int8":
+            return kbm.kb_update_q(*st, ids, values)
+        return (kbm.kb_update(st[0], ids, values),)
+
+    def grad(st, ids, g):
+        kb = (kb_lazy_grad(st[0], ids, g, zmax=ZMAX) if lazy else
+              st[0]._replace(table=st[0].table.at[ids].add(-LAZY_LR * g)))
+        return (kb, *st[1:])
+
+    return jax.jit(lookup), jax.jit(update), jax.jit(grad)
+
+
+_DONATING = [("dense", "fp32", True), ("pallas", "fp32", True),
+             ("sharded", "fp32", True), ("dense", "int8", True),
+             ("pallas", "int8", True), ("dense", "fp32", False),
+             ("pallas", "fp32", False), ("sharded", "fp32", False)]
+
+
+@pytest.mark.parametrize(
+    "backend,storage,lazy", _DONATING,
+    ids=[f"{b}-{s}-{'lazy' if lz else 'immediate'}"
+         for b, s, lz in _DONATING])
+def test_engine_ops_donate_the_state(backend, storage, lazy):
+    """Each lookup, update and lazy (or immediate) grad consumes the state
+    it was given: every leaf of it is deleted afterwards, so XLA scattered
+    in place, and ``donation_misses`` stays 0. Served rows and the final
+    state are bit-identical to the plain functional ops run un-donated on
+    the same batches (power-of-two sizes and distinct update ids, so the
+    engine pads and dedupes nothing)."""
+    eng = KBEngine(N, D, backend=_backends()[backend], storage=storage,
+                   lazy_update=lazy, lazy_lr=LAZY_LR, zmax=ZMAX)
+    ref = jax.tree.map(jnp.copy, (eng.state, eng._qscale, eng._qoffset))
+    ref = ref if storage == "int8" else ref[:1]
+    ref_lookup, ref_update, ref_grad = _reference_ops(storage, lazy)
+    rng = np.random.default_rng(7)
+    sequence = [("update", np.arange(N)), ("grad", rng.integers(0, N, 16)),
+                ("lookup", rng.integers(0, N, 8)),
+                ("grad", rng.integers(0, N, 32)),
+                ("update", rng.permutation(N)[:16]),
+                ("lookup", rng.integers(0, N, 32))]
+    for op, ids in sequence:
+        ids = ids.astype(np.int32)
+        rows = rng.standard_normal((ids.size, D)).astype(np.float32)
+        # a grad never touches the int8 scale and offset: it gets the
+        # KBState alone
+        before = jax.tree.leaves(eng.state) if op == "grad" else _live(eng)
+        if op == "lookup":
+            got = eng.lookup(ids)
+            want, ref = ref_lookup(ref, jnp.asarray(ids))
+            np.testing.assert_array_equal(got, np.asarray(want))
+        elif op == "update":
+            eng.update(ids, rows)
+            ref = ref_update(ref, jnp.asarray(ids), jnp.asarray(rows))
+        else:
+            eng.lazy_grad(ids, rows)
+            ref = ref_grad(ref, jnp.asarray(ids), jnp.asarray(rows))
+        assert all(a.is_deleted() for a in before), op
+        assert eng.donation_misses == 0, op
+    for got, want in zip(_live(eng), jax.tree.leaves(ref)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("backend,storage", [
+    ("dense", "fp32"), ("pallas", "fp32"), ("sharded", "fp32"),
+    ("pallas", "int8")])
+def test_warmup_leaves_the_live_state_untouched(backend, storage):
+    """``warmup`` runs its donating programs on a copy of the state: every
+    live leaf survives bit-identical (pending gradients included, which a
+    lookup on the live state would apply), and serving every warmed bucket
+    afterwards compiles nothing new."""
+    eng = KBEngine(N, D, backend=_backends()[backend], storage=storage,
+                   lazy_lr=LAZY_LR, zmax=ZMAX, key=jax.random.key(3))
+    rng = np.random.default_rng(3)
+    ids = rng.integers(0, N, 8)
+    eng.lazy_grad(ids, rng.standard_normal((8, D)).astype(np.float32))
+    live = _live(eng)
+    host = [np.array(jnp.copy(a)) for a in live]
+    eng.warmup(32)
+    assert not any(a.is_deleted() for a in live)
+    for a, h in zip(_live(eng), host):
+        np.testing.assert_array_equal(np.asarray(jnp.copy(a)), h)
+    compiled = (eng._lookup_fn._cache_size(), eng._lazy_fn._cache_size())
+    for b in (8, 16, 32):
+        ids = rng.integers(0, N, b)
+        eng.lookup(ids)
+        eng.lazy_grad(ids, rng.standard_normal((b, D)).astype(np.float32))
+    assert (eng._lookup_fn._cache_size(),
+            eng._lazy_fn._cache_size()) == compiled
+    assert eng.donation_misses == 0
 
 
 def test_engine_update_dedupes_last_writer_wins():
@@ -517,3 +628,50 @@ def test_ivf_refresher_rebuilds_off_the_serving_path():
     assert refresher.rebuilds >= 2, refresher.rebuilds
     assert srv.engine.search_stats["ivf"] > 0
     assert served > 0
+
+
+def test_ivf_refresher_snapshots_under_donating_writes():
+    """A writer streams lazy_grad and lookup calls, whose programs donate
+    the table, while the refresher rebuilds from it: the refresher copies
+    the table under the engine's swap lock, so every rebuild completes
+    without error, searches stay valid and no donation is missed."""
+    n, d = 512, 16
+    table = _clustered_table(n, d, 8, seed=6)
+    srv = KnowledgeBankServer(n, d, search_mode="ivf", ann_nlist=8,
+                              ann_nprobe=4)
+    srv.update(np.arange(n), table)
+    refresher = srv.start_ann_refresher(rebuild_rows=64, iters=4,
+                                        min_period_s=0.001)
+    stop, errors, refresh_errors = threading.Event(), [], []
+
+    def writer():
+        rng = np.random.default_rng(6)
+        try:
+            while not stop.is_set():
+                ids = rng.integers(0, n, 32)
+                srv.lazy_grad(ids, 0.01 * rng.standard_normal(
+                    (32, d)).astype(np.float32))
+                rows = srv.lookup(ids)
+                assert rows.shape == (32, d) and np.isfinite(rows).all()
+        except Exception as e:
+            errors.append(e)
+
+    t = threading.Thread(target=writer, daemon=True)
+    t.start()
+    deadline = time.monotonic() + 40.0
+    try:
+        while refresher.rebuilds < 3 and time.monotonic() < deadline:
+            s, i = srv.nn_search(table[:4] + 0.01, 5)
+            assert s.shape == (4, 5) and np.isfinite(s).all()
+            assert ((i >= 0) & (i < n)).all()
+            if refresher.last_error is not None:
+                refresh_errors.append(refresher.last_error)
+    finally:
+        stop.set()
+        t.join(timeout=10.0)
+        srv.close()
+    assert not t.is_alive()
+    assert not errors, errors
+    assert not refresh_errors and refresher.last_error is None
+    assert refresher.rebuilds >= 2, refresher.rebuilds
+    assert srv.engine.donation_misses == 0
